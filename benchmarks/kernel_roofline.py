@@ -20,25 +20,25 @@ def run() -> list:
     buf = np.frombuffer(synth_data(size), np.uint8)
     words = jnp.asarray(buf.view("<u4"))
 
-    from repro.kernels.ops import (_direct_hash_words,
-                                   _gear_hash_words_batch,
-                                   _sliding_hash_words_batch)
+    from repro.kernels.ops import (direct_hash_device,
+                                   gear_hash_batch_device,
+                                   sliding_hash_batch_device)
     segs = jnp.asarray(np.ascontiguousarray(buf.reshape(-1, 4096)).view(
         "<u4"))
     lens = jnp.full((segs.shape[0],), segs.shape[1], jnp.int32)
 
     batch = words[None]                # B=1 row of the fused entry points
     cases = [
-        ("sliding_md5_stride1", _sliding_hash_words_batch.lower(
+        ("sliding_md5_stride1", sliding_hash_batch_device.lower(
             batch, w_words=12, phases=(0, 1, 2, 3))),
-        ("sliding_md5_stride4", _sliding_hash_words_batch.lower(
+        ("sliding_md5_stride4", sliding_hash_batch_device.lower(
             batch, w_words=12, phases=(0,))),
-        ("gear_v1", _gear_hash_words_batch.lower(batch, version=1)),
-        ("gear_v2_doubling", _gear_hash_words_batch.lower(batch,
-                                                          version=2)),
-        ("gear_v3_hybrid", _gear_hash_words_batch.lower(batch,
-                                                        version=3)),
-        ("direct_md5_4k", _direct_hash_words.lower(segs, lens)),
+        ("gear_v1", gear_hash_batch_device.lower(batch, version=1)),
+        ("gear_v2_doubling", gear_hash_batch_device.lower(batch,
+                                                           version=2)),
+        ("gear_v3_hybrid", gear_hash_batch_device.lower(batch,
+                                                         version=3)),
+        ("direct_md5_4k", direct_hash_device.lower(segs, lens)),
     ]
     for name, lowered in cases:
         an = analyze_hlo(lowered.compile().as_text())

@@ -69,6 +69,8 @@ def _write_json(path: str, rows, modules) -> None:
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     want = sys.argv[1:]
     print("name,us_per_call,derived")
     failed = 0

@@ -6,6 +6,7 @@ content-based chunking, with similarity detection and storage savings.
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.core import SAI, SAIConfig, make_store
 
 
@@ -34,6 +35,7 @@ def checkpoint_series(n_images, image_bytes, change_frac=0.15, seed=0):
     return out
 
 
+compile_cache.enable()
 images = checkpoint_series(n_images=5, image_bytes=2 << 20,
                            change_frac=0.15)
 total = sum(len(i) for i in images)

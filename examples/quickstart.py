@@ -9,12 +9,15 @@ catch a corruption.
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.core import CrystalTPU, SAI, SAIConfig, make_store
 from repro.kernels import ops
 
+compile_cache.enable()
 rng = np.random.default_rng(0)
 
-# 1) hashing primitives (Pallas kernels, interpret mode on CPU)
+# 1) hashing primitives (Pallas kernels: Mosaic on a TPU, the interpreter
+#    elsewhere)
 data = rng.integers(0, 256, 64 << 10, dtype=np.uint8).tobytes()
 digests, final = ops.hash_blocks(data, block_bytes=4096)
 print(f"direct hashing: {len(digests)} block digests, "

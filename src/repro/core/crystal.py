@@ -109,9 +109,10 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.kernels import ops
+from repro.kernels import layout, ops
 from repro.obs import HeartbeatBoard
 from repro.obs import metrics as metrics_mod
+from repro.roofline.analysis import HASH_OPS_PER_BYTE, hash_cost_seed
 
 
 LANES = ("fg", "batch", "scrub")       # dequeue priority, highest first
@@ -248,19 +249,12 @@ def _normalize_direct(data: np.ndarray, meta: Dict[str, Any]):
 
 def _cost_seeds() -> Dict[str, Tuple[float, float]]:
     """kind -> (sec_per_byte, launch_overhead_s) seeds for the cost
-    model, derived from the roofline hash-kernel op counts; a static
-    fallback keeps the engine importable if the roofline package is
-    unavailable (stripped deployments)."""
-    try:
-        from repro.roofline.analysis import HASH_OPS_PER_BYTE, \
-            hash_cost_seed
-        out = {}
-        for kind in HASH_OPS_PER_BYTE:
-            s = hash_cost_seed(kind)
-            out[kind] = (s["sec_per_byte"], s["launch_overhead_s"])
-        return out
-    except Exception:
-        return {k: (5e-8, 2e-3) for k in ("direct", "sliding", "gear")}
+    model, derived from the roofline hash-kernel op counts."""
+    out = {}
+    for kind in HASH_OPS_PER_BYTE:
+        s = hash_cost_seed(kind)
+        out[kind] = (s["sec_per_byte"], s["launch_overhead_s"])
+    return out
 
 
 class KernelCostModel:
@@ -415,16 +409,19 @@ class _DeviceState:
     fails over, and per-(kind, width-bucket) launch-latency EWMAs.
     Mutable fields are guarded by the engine lock; the queue has its own
     condition variable (never acquired while holding the engine lock in
-    a blocking wait)."""
+    a blocking wait).  ``interpret`` says whether this device's launches
+    run the Pallas interpreter — derived from its platform by the same
+    rule the kernels lower by, so it is False on every TPU."""
 
-    __slots__ = ("index", "device", "queue", "queued_bytes", "pending_s",
-                 "slowdown", "last_fuse_key", "picked", "ewma_launch_s",
-                 "ewma_bucket_s", "jobs", "launches", "bytes", "restarts",
-                 "launch_hist")
+    __slots__ = ("index", "device", "interpret", "queue", "queued_bytes",
+                 "pending_s", "slowdown", "last_fuse_key", "picked",
+                 "ewma_launch_s", "ewma_bucket_s", "jobs", "launches",
+                 "bytes", "restarts", "launch_hist")
 
     def __init__(self, index: int, device, launch_hist=None):
         self.index = index
         self.device = device
+        self.interpret = layout.interprets(device.platform)
         self.queue = LaneQueue()
         self.queued_bytes = 0
         self.pending_s = 0.0
@@ -447,7 +444,7 @@ class _DeviceState:
 
     def stats_row(self) -> Dict[str, Any]:
         return {"jobs": self.jobs, "launches": self.launches,
-                "bytes": self.bytes,
+                "bytes": self.bytes, "interpret": int(self.interpret),
                 "ewma_launch_s": self.ewma_launch_s,
                 "ewma_bucket_s": {f"{k}/{w}": v for (k, w), v
                                   in self.ewma_bucket_s.items()},
@@ -516,7 +513,7 @@ class CrystalTPU:
 
     def __init__(self, devices=None, buffer_reuse: bool = True,
                  overlap: bool = True, n_slots: int = 8,
-                 interpret: bool = True, coalesce: bool = True,
+                 coalesce: bool = True,
                  max_batch: int = 64, coalesce_window_s: float = 0.0,
                  max_fused_rows: int = 4096,
                  max_fused_bytes: int = 64 << 20,
@@ -527,7 +524,6 @@ class CrystalTPU:
                             else jax.devices())
         self.buffer_reuse = buffer_reuse
         self.overlap = overlap
-        self.interpret = interpret
         self.coalesce = coalesce
         self.max_batch = max(1, int(max_batch))
         self.coalesce_window_s = coalesce_window_s
@@ -1098,8 +1094,7 @@ class CrystalTPU:
         self._stage_sync(dev_words)
         t1 = time.perf_counter()
         # stage 3: ONE kernel launch for the fused batch, device-resident
-        dig = ops.direct_hash_device(dev_words, dev_lens,
-                                     interpret=self.interpret)
+        dig = ops.direct_hash_device(dev_words, dev_lens)
         self._stage_sync(dig)
         t2 = time.perf_counter()
         # stage 4: transfer out (digests only — 16 B per row)
@@ -1145,21 +1140,19 @@ class CrystalTPU:
             stride = int(batch[0].meta.get("stride", 4))
             phases = tuple(range(0, 4, stride))
             out = ops.sliding_hash_batch_device(dev_words, window // 4,
-                                                phases,
-                                                interpret=self.interpret)
+                                                phases)
             self._stage_sync(out)
             t2 = time.perf_counter()
-            host = np.asarray(out)                       # [B, R, Wc]
+            host = np.asarray(out)               # [B, R, Wc/128, 128]
             for i, j in enumerate(batch):
                 n_off = (lens[i] - window) // stride + 1
                 j.result = ops.sliding_finish(host[i], phases, n_off)
         else:
             out = ops.gear_hash_batch_device(
-                dev_words, interpret=self.interpret,
-                version=int(batch[0].meta.get("version", 1)))
+                dev_words, version=int(batch[0].meta.get("version", 1)))
             self._stage_sync(out)
             t2 = time.perf_counter()
-            host = np.asarray(out)                       # [B, 4, Wc]
+            host = np.asarray(out)               # [B, 4, Wc/128, 128]
             for i, j in enumerate(batch):
                 j.result = ops.gear_finish(host[i], lens[i])
         t3 = time.perf_counter()

@@ -2,9 +2,9 @@
 
 All APIs take/return numpy-friendly arrays; padding, word packing, byte-
 phase strip construction and output interleaving live here so the kernels
-stay shape-regular.  ``interpret=True`` (the CPU default here) executes
-the kernel bodies in Python via the Pallas interpreter; on TPU the same
-calls lower to Mosaic.
+stay shape-regular.  A launch placed on a TPU lowers its kernel through
+Mosaic; on any other backend (the CPU the tests run on) the same call
+runs the Pallas interpreter (``layout.pallas_call``).
 
 Two layers are exposed:
   * convenience wrappers (``direct_hash``, ``sliding_window_hash``,
@@ -43,42 +43,33 @@ from repro.kernels import sliding_md5 as slide_k
 # --------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _direct_hash_words(data: jax.Array, lens_w: jax.Array,
-                       interpret: bool = True) -> jax.Array:
-    """data: [N, W] uint32; lens_w: [N] int32 -> [N, 4] uint32 digests."""
-    N, W = data.shape
-    n_pad = (-N) % md5_k.TILE_N
-    # bound the chunk grid to ~32 steps for long segments (grid dispatch
-    # dominates on the interpreter; on TPU this is simply a larger VMEM
-    # message block, capped at 16*128*TILE_N words ~ 1 MB)
-    n_chunks = (W + 3 + 15) // 16
-    chunk_tile = min(512, max(md5_k.CHUNK_TILE, -(-n_chunks // 8)))
-    w_pad = (-(W + 3)) % (16 * chunk_tile) + 3
-    data = jnp.pad(data, ((0, n_pad), (0, w_pad)))
-    lens = jnp.pad(lens_w.astype(jnp.int32), (0, n_pad))
-    dig = md5_k.md5_pallas(data.T, lens, interpret=interpret,
-                           chunk_tile=chunk_tile)              # [4, Npad]
-    return dig.T[:N]
-
-
-def direct_hash_device(words: jax.Array, lens_w: jax.Array,
-                       interpret: bool = True) -> jax.Array:
+@jax.jit
+def direct_hash_device(words: jax.Array, lens_w: jax.Array) -> jax.Array:
     """Device-resident direct hashing: ``words`` [N, W] uint32 already on
     the target device, ``lens_w`` [N] int32 word lengths.  Returns the
     [N, 4] uint32 digest array *on device* (callers pull it with
     ``digest_bytes`` — 16 B/row, the only host transfer)."""
-    return _direct_hash_words(words, lens_w, interpret=interpret)
+    N, W = words.shape
+    n_pad = (-N) % md5_k.TILE_N
+    # bound the chunk grid to ~8 steps per segment tile (grid dispatch
+    # dominates on the interpreter; on TPU this is simply a larger VMEM
+    # message block, capped at 16*512*TILE_N words = 4 MiB)
+    n_chunks = (W + 3 + 15) // 16
+    chunk_tile = min(512, max(md5_k.CHUNK_TILE, -(-n_chunks // 8)))
+    w_pad = (-(W + 3)) % (16 * chunk_tile) + 3
+    data = jnp.pad(words, ((0, n_pad), (0, w_pad)))
+    lens = jnp.pad(lens_w.astype(jnp.int32), (0, n_pad))
+    dig = md5_k.md5_pallas(data.T, lens, chunk_tile=chunk_tile)  # [4, Npad]
+    return dig.T[:N]
 
 
 def digest_bytes(dig) -> np.ndarray:
     """[N, 4] uint32 digests (device or host) -> [N, 16] uint8 host."""
-    dig = np.asarray(dig)
-    return dig.astype("<u4").view(np.uint8).reshape(dig.shape[0], 16)
+    dig = np.ascontiguousarray(dig, dtype="<u4")
+    return dig.view(np.uint8).reshape(dig.shape[0], 16)
 
 
-def direct_hash(segments: np.ndarray, lens_bytes=None,
-                interpret: bool = True) -> np.ndarray:
+def direct_hash(segments: np.ndarray, lens_bytes=None) -> np.ndarray:
     """MD5 digests of N word-aligned segments.
 
     segments: [N, seg_bytes/4] uint32 (or uint8 [N, seg_bytes]);
@@ -97,13 +88,11 @@ def direct_hash(segments: np.ndarray, lens_bytes=None,
         lens_bytes = np.asarray(lens_bytes)
         assert np.all(lens_bytes % 4 == 0)
         lens_w = (lens_bytes // 4).astype(np.int32)
-    dig = direct_hash_device(jnp.asarray(segments), jnp.asarray(lens_w),
-                             interpret=interpret)
+    dig = direct_hash_device(jnp.asarray(segments), jnp.asarray(lens_w))
     return digest_bytes(dig)
 
 
-def hash_blocks(data: bytes, block_bytes: int,
-                interpret: bool = True) -> Tuple[np.ndarray, bytes]:
+def hash_blocks(data: bytes, block_bytes: int) -> Tuple[np.ndarray, bytes]:
     """Fixed-size-block direct hashing of a buffer (paper's fixed-block
     content addressability).  Returns ([n_blocks, 16] digests, final
     digest bytes = md5 over the concatenated digests, computed host-side
@@ -115,7 +104,7 @@ def hash_blocks(data: bytes, block_bytes: int,
     lens = np.full((n,), block_bytes, np.int64)
     lens[-1] = len(data) - (n - 1) * block_bytes
     lens = ((lens + 3) // 4 * 4)                  # word-align tail
-    digs = direct_hash(arr, lens, interpret=interpret)
+    digs = direct_hash(arr, lens)
     final = hashlib.md5(digs.tobytes()).digest()
     return digs, final
 
@@ -125,8 +114,8 @@ def hash_blocks(data: bytes, block_bytes: int,
 # --------------------------------------------------------------------------
 def _pick_tile(L: int, base: int) -> int:
     """Tile width bounding grid steps to ~64 (VMEM stays < ~0.5 MB/input
-    block; interpret mode traces the grid as a Python loop, so step count
-    dominates trace time on CPU)."""
+    block; the interpreter pays per grid step, so step count dominates
+    its run time on CPU)."""
     t = base
     while L // t > 64 and t < (1 << 15):
         t *= 2
@@ -134,21 +123,22 @@ def _pick_tile(L: int, base: int) -> int:
 
 
 def sliding_hash_device(words: jax.Array, w_words: int,
-                        phases: Tuple[int, ...],
-                        interpret: bool = True) -> jax.Array:
+                        phases: Tuple[int, ...]) -> jax.Array:
     """Device-resident sliding-window hashing: ``words`` [L] uint32 on
-    the target device.  Returns the [R, Wc] uint32 per-phase hash matrix
-    on device; ``sliding_finish`` interleaves it host-side.  (The B=1
-    case of the batched path — one strip builder and one jit cache.)"""
-    return _sliding_hash_words_batch(words[None], w_words, phases,
-                                     interpret=interpret)[0]
+    the target device.  Returns the [R, Wc/128, 128] uint32 per-phase
+    hash matrix on device; ``sliding_finish`` interleaves it host-side.
+    (The B=1 case of the batched path — one strip builder and one jit
+    cache.)"""
+    return sliding_hash_batch_device(words[None], w_words, phases)[0]
 
 
 def sliding_finish(out: np.ndarray, phases: Tuple[int, ...],
                    n_off: int) -> np.ndarray:
-    """Interleave phase rows: offset o = 4q + phases[r] -> out[r, q]."""
+    """Interleave phase rows: offset o = 4q + phases[r] -> out[r, q]
+    (``out`` [R, Wc/128, 128] as the kernel writes it)."""
     if n_off <= 0:                 # input shorter than one window
         return np.empty((0,), np.uint32)
+    out = out.reshape(len(phases), -1)
     R, Wc = out.shape
     inter = np.empty((Wc * R,), np.uint32)
     for i, r in enumerate(phases):
@@ -173,39 +163,29 @@ def _byte_phase_strips_batch(words: jax.Array, phases: Tuple[int, ...],
     return jnp.stack(strips, axis=1)                     # [B, R, L+pad]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("w_words", "phases", "interpret"))
-def _sliding_hash_words_batch(words: jax.Array, w_words: int,
-                              phases: Tuple[int, ...],
-                              interpret: bool = True) -> jax.Array:
-    B, L = words.shape
-    T = _pick_tile(L, slide_k.TILE_W)
-    w_cap = ((L + T - 1) // T) * T
-    pad = w_cap - L + T
-    strips = _byte_phase_strips_batch(words, phases, pad)  # [B,R,w_cap+T]
-    R = len(phases)
-    out = slide_k.sliding_md5_pallas(
-        strips.reshape(B * R, w_cap + T), w_words,
-        interpret=interpret, tile=T)                       # [B*R, 4, w_cap]
-    return out[:, 0, :].reshape(B, R, w_cap)
-
-
+@functools.partial(jax.jit, static_argnames=("w_words", "phases"))
 def sliding_hash_batch_device(words: jax.Array, w_words: int,
-                              phases: Tuple[int, ...],
-                              interpret: bool = True) -> jax.Array:
+                              phases: Tuple[int, ...]) -> jax.Array:
     """Fused multi-buffer sliding-window hashing: ``words`` [B, L] uint32
     on the target device, one row per job (rows zero-padded to the batch
     width).  ONE kernel launch covers all B*R strips; returns the
-    [B, R, Wc] uint32 per-job/per-phase hash matrix on device — callers
-    slice row b and run ``sliding_finish`` with that job's own offset
-    count."""
-    return _sliding_hash_words_batch(words, w_words, phases,
-                                     interpret=interpret)
+    [B, R, Wc/128, 128] uint32 per-job/per-phase hash matrix on device —
+    callers slice row b and run ``sliding_finish`` with that job's own
+    offset count."""
+    B, L = words.shape
+    T = _pick_tile(L, slide_k.TILE_W)
+    w_cap = ((L + T - 1) // T) * T
+    strips = _byte_phase_strips_batch(
+        words, phases, w_cap - L + slide_k.HALO_W)  # [B, R, w_cap+HALO_W]
+    R = len(phases)
+    out = slide_k.sliding_md5_pallas(
+        strips.reshape(B * R, w_cap + slide_k.HALO_W), w_words,
+        tile=T)                                  # [B*R, w_cap/128, 128]
+    return out.reshape(B, R, *out.shape[1:])
 
 
 def sliding_window_hash(data: bytes | np.ndarray, window: int = 48,
-                        stride: int = 1,
-                        interpret: bool = True) -> np.ndarray:
+                        stride: int = 1) -> np.ndarray:
     """MD5 (digest word 'a') of every ``window``-byte window at byte
     offsets 0, stride, 2*stride, ...  window % 4 == 0, window <= 52;
     stride in {1, 2, 4}.  Returns [n_off] uint32."""
@@ -220,53 +200,43 @@ def sliding_window_hash(data: bytes | np.ndarray, window: int = 48,
     pad = (-L) % 4
     words = jnp.asarray(np.pad(buf, (0, pad)).view("<u4"))
     phases = tuple(range(0, 4, stride))
-    out = np.asarray(sliding_hash_device(words, window // 4, phases,
-                                         interpret=interpret))  # [R, Wc]
+    out = np.asarray(sliding_hash_device(words, window // 4, phases))
     return sliding_finish(out, phases, n_off)
 
 
 # --------------------------------------------------------------------------
 # gear rolling hash (beyond-paper CDC)
 # --------------------------------------------------------------------------
-def gear_hash_device(words: jax.Array, interpret: bool = True,
-                     version: int = 1) -> jax.Array:
+def gear_hash_device(words: jax.Array, version: int = 1) -> jax.Array:
     """Device-resident gear hashing: ``words`` [L] uint32 on the target
-    device.  Returns the [4, w_cap] uint32 phase matrix on device;
-    ``gear_finish`` flattens it host-side.  (The B=1 case of the
+    device.  Returns the [4, w_cap/128, 128] uint32 phase matrix on
+    device; ``gear_finish`` flattens it host-side.  (The B=1 case of the
     batched path — one pad/launch wrapper and one jit cache.)"""
-    return _gear_hash_words_batch(words[None], interpret=interpret,
-                                  version=version)[0]
+    return gear_hash_batch_device(words[None], version=version)[0]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "version"))
-def _gear_hash_words_batch(words: jax.Array, interpret: bool = True,
-                           version: int = 1) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("version",))
+def gear_hash_batch_device(words: jax.Array, version: int = 1) -> jax.Array:
+    """Fused multi-buffer gear hashing: ``words`` [B, L] uint32 on the
+    target device, one row per job (rows zero-padded to the batch width).
+    ONE kernel launch covers the whole batch; returns the
+    [B, 4, Wc/128, 128] uint32 phase matrices on device — callers slice
+    row b and flatten it with ``gear_finish`` using that job's own byte
+    length."""
     B, L = words.shape
     T = _pick_tile(L, gear_k.TILE_W)
     w_cap = ((L + T - 1) // T) * T
     strip = jnp.pad(words, ((0, 0), (T, w_cap - L)))   # per-row history 0s
-    return gear_k.gear_pallas(strip, interpret=interpret,
-                              version=version, tile=T)       # [B, 4, w_cap]
-
-
-def gear_hash_batch_device(words: jax.Array, interpret: bool = True,
-                           version: int = 1) -> jax.Array:
-    """Fused multi-buffer gear hashing: ``words`` [B, L] uint32 on the
-    target device, one row per job (rows zero-padded to the batch width).
-    ONE kernel launch covers the whole batch; returns the [B, 4, Wc]
-    uint32 phase matrices on device — callers slice row b and flatten it
-    with ``gear_finish`` using that job's own byte length."""
-    return _gear_hash_words_batch(words, interpret=interpret,
-                                  version=version)
+    return gear_k.gear_pallas(strip, version=version, tile=T)
 
 
 def gear_finish(out: np.ndarray, n_bytes: int) -> np.ndarray:
-    """Flatten [4, w_cap] phase matrix to per-byte order (4q + r)."""
-    return out.T.reshape(-1)[:n_bytes]
+    """Flatten the [4, w_cap/128, 128] phase matrix to per-byte order
+    (4q + r)."""
+    return out.reshape(4, -1).T.reshape(-1)[:n_bytes]
 
 
-def gear_hash(data: bytes | np.ndarray, interpret: bool = True,
-              version: int = 1) -> np.ndarray:
+def gear_hash(data: bytes | np.ndarray, version: int = 1) -> np.ndarray:
     """Windowed gear hash at every byte position.  Returns [L] uint32.
     Positions < 32 differ from ref (zero-history convention) — chunking
     never places boundaries inside the minimum chunk size anyway.
@@ -278,8 +248,7 @@ def gear_hash(data: bytes | np.ndarray, interpret: bool = True,
     L = len(buf)
     pad = (-L) % 4
     words = jnp.asarray(np.pad(buf, (0, pad)).view("<u4"))
-    out = np.asarray(gear_hash_device(words, interpret=interpret,
-                                      version=version))
+    out = np.asarray(gear_hash_device(words, version=version))
     return gear_finish(out, L)
 
 

@@ -31,11 +31,12 @@ import traceback
 
 import jax
 
-from repro import compat
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs import ARCH_NAMES, get_config, get_shape
-from repro.launch.mesh import make_production_mesh, make_shard_ctx
+from repro.launch.mesh import (auto_mesh, make_production_mesh,
+                               make_shard_ctx)
 from repro.models.model import build_model
 from repro.models.sharding import zero1_spec
 from repro.optim import make_optimizer, make_schedule
@@ -91,9 +92,9 @@ def lower_cell(arch: str, shape_name: str, mesh_kind: str,
     if dp and tp:
         # §Perf axis-rebalance variant: same chip count, different split
         if mesh_kind == "multi":
-            mesh = jax.make_mesh((2, dp, tp), ("pod", "data", "model"))
+            mesh = auto_mesh((2, dp, tp), ("pod", "data", "model"))
         else:
-            mesh = jax.make_mesh((dp, tp), ("data", "model"))
+            mesh = auto_mesh((dp, tp), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     ctx = make_shard_ctx(mesh)
@@ -179,7 +180,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
             v = getattr(mem, k, None)
             if v is not None:
                 mem_d[k] = int(v)
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     cost_d = {k: float(v) for k, v in cost.items()
               if isinstance(v, (int, float)) and k in
               ("flops", "bytes accessed", "transcendentals",
@@ -235,6 +236,7 @@ def all_cells():
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

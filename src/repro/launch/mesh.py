@@ -14,10 +14,18 @@ import jax
 from repro.models.sharding import ShardCtx
 
 
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto: shardings are propagated by
+    the compiler from ``NamedSharding`` annotations, as this code expects
+    (``jax.make_mesh`` defaults to Explicit axes)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_shard_ctx(mesh) -> ShardCtx:
@@ -29,4 +37,4 @@ def make_shard_ctx(mesh) -> ShardCtx:
 def make_host_mesh():
     """Single-process mesh over whatever devices exist (tests/examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return auto_mesh((n, 1), ("data", "model"))

@@ -11,11 +11,13 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.launch.train import preset_config
 from repro.models.model import build_model
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--preset", default="100m",

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.core import CrystalTPU, SAI, SAIConfig, make_store
 from repro.data import make_pipeline
@@ -63,6 +64,7 @@ def preset_config(arch: str, preset: str):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--preset", default="100m",

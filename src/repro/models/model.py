@@ -19,7 +19,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -113,10 +112,10 @@ class LMModel:
             shapes["head"] = (cfg.d_model, cfg.vocab_size)
         for i, (mixer, ffn) in enumerate(self.kinds):
             sub = self._sublayer_shapes(mixer, ffn)
-            stacked = compat.tree_map(lambda s: (self.n_super, *s), sub,
+            stacked = jax.tree.map(lambda s: (self.n_super, *s), sub,
                                    is_leaf=lambda s: isinstance(s, tuple))
             shapes["blocks"][f"pos{i}"] = stacked
-        return compat.tree_map(
+        return jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s, self.pdt), shapes,
             is_leaf=lambda s: isinstance(s, tuple))
 
@@ -126,7 +125,7 @@ class LMModel:
 
     def init(self, rng: jax.Array) -> Pytree:
         shapes = self.param_shapes()
-        leaves, treedef = compat.tree_flatten_with_path(shapes)
+        leaves, treedef = jax.tree.flatten_with_path(shapes)
         keys = jax.random.split(rng, len(leaves))
         d = self.cfg.d_model
 
@@ -150,7 +149,7 @@ class LMModel:
                     * scale).astype(dtype)
 
         out = [init_leaf(p, s, k) for (p, s), k in zip(leaves, keys)]
-        return compat.tree_unflatten(treedef, out)
+        return jax.tree.unflatten(treedef, out)
 
     # ------------------------------------------------------------------
     # forward components

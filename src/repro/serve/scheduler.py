@@ -22,8 +22,6 @@ from collections import deque
 from typing import Deque, Dict, List, Optional
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -51,7 +49,7 @@ class ContinuousBatcher:
         self.active: Dict[int, Request] = {}        # slot -> request
         self.finished: List[Request] = []
         self.slot_pos = np.zeros((batch_slots,), np.int64)
-        self.cache = compat.tree_map(
+        self.cache = jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype),
             model.cache_shapes(batch_slots, capacity))
         self._prefill_one = jax.jit(
@@ -80,7 +78,7 @@ class ContinuousBatcher:
             def put(full, one):
                 return full.at[:, slot:slot + 1].set(one.astype(full.dtype))
 
-            self.cache = compat.tree_map(put, self.cache, cache1)
+            self.cache = jax.tree.map(put, self.cache, cache1)
             req.out_tokens.append(int(jnp.argmax(logits, -1)[0]))
             req.first_token_s = time.time()
             self.slot_pos[slot] = len(req.prompt)

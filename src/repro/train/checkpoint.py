@@ -28,14 +28,14 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
-from repro import compat
 from repro.core.sai import SAI, WriteStats
 
 
 def _flatten(tree) -> List[Tuple[str, np.ndarray]]:
-    leaves = compat.tree_flatten_with_path(tree)[0]
+    leaves = jax.tree.flatten_with_path(tree)[0]
     out = []
     for path, leaf in leaves:
         key = "/".join(str(getattr(p, "key", p)) for p in path)
@@ -95,8 +95,8 @@ class CACheckpointer:
     def async_save(self, step: int, params, opt_state=None,
                    extra: Optional[dict] = None) -> threading.Thread:
         """Non-blocking save: snapshot to host, hash+store in background."""
-        snap_p = compat.tree_map(np.asarray, params)
-        snap_o = compat.tree_map(np.asarray, opt_state) \
+        snap_p = jax.tree.map(np.asarray, params)
+        snap_o = jax.tree.map(np.asarray, opt_state) \
             if opt_state is not None else None
         self.wait()
         t = threading.Thread(

@@ -20,8 +20,6 @@ import time
 from typing import Callable, Dict, List, Optional
 
 import jax
-
-from repro import compat
 import numpy as np
 
 
@@ -97,7 +95,7 @@ class TrainSupervisor:
 
 def _cast_like(template, restored):
     """Restore numpy state into the template pytree's dtypes/devices."""
-    return compat.tree_map(
+    return jax.tree.map(
         lambda t, r: jax.numpy.asarray(r, dtype=t.dtype), template, restored)
 
 

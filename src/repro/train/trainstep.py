@@ -17,7 +17,6 @@ from typing import Any, Dict, Optional
 
 import jax
 
-from repro import compat
 import jax.numpy as jnp
 
 CE_CHUNK = 512
@@ -102,26 +101,26 @@ def make_train_step(model, optimizer, microbatches: int = 1,
                 B = x.shape[0]
                 return x.reshape(microbatches, B // microbatches,
                                  *x.shape[1:])
-            mb = compat.tree_map(split, batch)
+            mb = jax.tree.map(split, batch)
 
             def body(carry, mbatch):
                 gsum, lsum = carry
                 (loss, _), g = grad_fn(params, mbatch)
-                gsum = compat.tree_map(
+                gsum = jax.tree.map(
                     lambda a, b: a + b.astype(jnp.float32), gsum, g)
                 return (gsum, lsum + loss), None
 
-            gzero = compat.tree_map(
+            gzero = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
             (grads, lsum), _ = jax.lax.scan(
                 body, (gzero, jnp.zeros((), jnp.float32)), mb)
-            grads = compat.tree_map(lambda g: g / microbatches, grads)
+            grads = jax.tree.map(lambda g: g / microbatches, grads)
             loss = lsum / microbatches
             metrics = {"ce": loss, "aux": jnp.zeros((), jnp.float32)}
 
         gnorm = jnp.sqrt(sum(
             jnp.sum(jnp.square(g.astype(jnp.float32)))
-            for g in compat.tree_leaves(grads)))
+            for g in jax.tree.leaves(grads)))
         params, opt_state = optimizer.update(grads, opt_state, params, step)
         metrics = dict(metrics, loss=loss, grad_norm=gnorm,
                        lr=optimizer.lr_fn(step))

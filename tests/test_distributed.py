@@ -33,7 +33,8 @@ def test_sharded_train_step_runs_small_mesh():
         from repro.optim import make_optimizer, make_schedule
         from repro.train.trainstep import make_train_step
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         ctx = ShardCtx(mesh=mesh, dp_axes=("data",))
         cfg = get_smoke_config("llama3-8b")
         model_s = build_model(cfg, ctx)
@@ -82,7 +83,8 @@ def test_grad_compression_cross_pod():
         from jax.sharding import PartitionSpec as P
         from repro.optim.grad_compress import (make_cross_pod_sync,
                                                init_error_state)
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         specs = {"w": P(None, None)}
         sync = make_cross_pod_sync(mesh, specs)
         rng = np.random.default_rng(0)

@@ -23,8 +23,7 @@ def test_scan_flops_scaled_by_trip_count():
     an = analyze_hlo(compiled.as_text())
     one_matmul = 2 * 128 * 256 * 256
     assert an["flops"] == pytest.approx(10 * one_matmul, rel=0.01)
-    from repro.compat import cost_analysis
-    xla_flops = cost_analysis(compiled)["flops"]
+    xla_flops = compiled.cost_analysis()["flops"]
     assert xla_flops == pytest.approx(one_matmul, rel=0.01)
 
 
