@@ -64,13 +64,32 @@ Engine mesh (this module's multi-device structure):
                restarts, and ``manager_restarts`` counts the event.
 
 ``snapshot_stats()`` exposes the flat engine counters plus
-``per_device`` (jobs, launches, bytes, EWMA launch latency overall and
-per ``(kind, width-bucket)``, queue depth, queued padded bytes, pending
-model-seconds, slowdown, restarts), ``policy`` (current caps + octave
-span), and ``sharded_jobs`` / ``shards`` / ``manager_restarts``.
+``per_device`` (jobs, launches, bytes, EWMA launch latency, queue depth,
+queued padded bytes, pending model-seconds, slowdown, restarts, and the
+launch-phase counters below), ``policy`` (current caps + octave span),
+and ``sharded_jobs`` / ``shards`` / ``manager_restarts``.
+
 ``queue_depth(lane, device=...)`` reads one device's backlog;
 without ``device`` it sums the mesh (the node runtime's scrub backoff
 and the gateway read it).
+
+Launch phases: a launch runs five phases on its manager thread —
+``stage`` (zero and fill the staging matrix), ``put`` (``device_put``
+of the inputs), ``call`` (the jitted ``ops.*_device`` call), ``wait``
+(the blocking pull of the result) and ``finish`` (``gear_finish`` /
+``sliding_finish``, or slicing digests into each job).  Each phase is
+one ``repro.obs.span`` named ``engine/<phase>:<kind>`` (on the JAX
+profiler's clock while it traces, carrying ``device``, ``launch``,
+``rows``, ``padded_bytes`` and the jobs' ``seq``), and its one timing
+feeds ``Job.timings[<phase>]`` and the per-device sum
+``phase_s[kind][phase]``.  Beside them each device counts ``queue_s``
+(job submit to launch start, summed over jobs), ``h2d_bytes`` (bytes
+handed to ``device_put``), and for direct launches ``md5_rows`` (rows
+used) and ``md5_lane_rows`` (rows the MD5 kernel computes, padded to
+its lane tile); ``bytes`` counts the useful bytes.  Only successful
+launches count, as in ``launch_hist``.  Under ``overlap=False``,
+stage + put, call and wait + finish are the paper's Table-1 stages
+(copy in, kernel, copy out).
 
 Data stays device-resident from ``device_put`` through the kernel: hosts
 prepare word-packed staging buffers, the device buffer is handed straight
@@ -100,6 +119,8 @@ from __future__ import annotations
 
 import atexit
 import collections
+import contextlib
+import itertools
 import queue
 import threading
 import time
@@ -110,12 +131,13 @@ import jax
 import numpy as np
 
 from repro.kernels import layout, ops
-from repro.obs import HeartbeatBoard
+from repro.obs import HeartbeatBoard, span
 from repro.obs import metrics as metrics_mod
 from repro.roofline.analysis import HASH_OPS_PER_BYTE, hash_cost_seed
 
 
 LANES = ("fg", "batch", "scrub")       # dequeue priority, highest first
+PHASES = ("stage", "put", "call", "wait", "finish")   # of one launch
 
 
 class LaneQueue:
@@ -188,7 +210,12 @@ class Job:                             # numpy fields, and the manager's
     result: Any = None
     error: Optional[BaseException] = None
     done: threading.Event = field(default_factory=threading.Event)
+    # seconds of each phase of the launch that ran this job (PHASES;
+    # batch-wide: every job of a fused launch shares them)
     timings: Dict[str, float] = field(default_factory=dict)
+    # engine-wide submission number (shard children share their
+    # parent's): joins a submitter's spans to the launches that ran it
+    seq: int = -1
     # normalized 'direct' payload (set at submit time)
     rows: Optional[np.ndarray] = None
     lens: Optional[np.ndarray] = None
@@ -402,11 +429,39 @@ class FusionPolicy:
                 "ewma_launch_s": self._wall}
 
 
+class _Launch:
+    """The phases of one launch on one device: ``phase(name)`` opens the
+    phase's span and keeps its duration in ``phase_s``; the launch's
+    byte and lane counts wait here until ``_retire`` adds them to the
+    device's sums."""
+
+    __slots__ = ("kind", "ids", "phase_s", "h2d_bytes", "md5_rows",
+                 "md5_lane_rows")
+
+    def __init__(self, kind: str, device: int, launch: int,
+                 batch: List[Job]):
+        self.kind = kind
+        self.ids: Dict[str, Any] = {"device": device, "launch": launch,
+                                    "seq": [j.seq for j in batch]}
+        self.phase_s: Dict[str, float] = {}
+        self.h2d_bytes = self.md5_rows = self.md5_lane_rows = 0
+
+    def shape(self, rows: int, padded_bytes: int) -> None:
+        self.ids["rows"] = rows
+        self.ids["padded_bytes"] = padded_bytes
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        with span(f"engine/{name}:{self.kind}", **self.ids) as sp:
+            yield
+        self.phase_s[name] = sp.duration_s
+
+
 class _DeviceState:
     """Per-device mesh state: a private lane queue, the backlog signals
     the dispatcher scores (queued padded bytes + pending model-seconds +
     EWMA observed/estimated slowdown), the picked list crash recovery
-    fails over, and per-(kind, width-bucket) launch-latency EWMAs.
+    fails over, the launch-latency EWMA, and the launch-phase counters.
     Mutable fields are guarded by the engine lock; the queue has its own
     condition variable (never acquired while holding the engine lock in
     a blocking wait).  ``interpret`` says whether this device's launches
@@ -415,8 +470,9 @@ class _DeviceState:
 
     __slots__ = ("index", "device", "interpret", "queue", "queued_bytes",
                  "pending_s", "slowdown", "last_fuse_key", "picked",
-                 "ewma_launch_s", "ewma_bucket_s", "jobs", "launches",
-                 "bytes", "restarts", "launch_hist")
+                 "ewma_launch_s", "jobs", "launches", "bytes", "restarts",
+                 "launch_hist", "n_launched", "phase_s", "queue_s",
+                 "h2d_bytes", "md5_rows", "md5_lane_rows")
 
     def __init__(self, index: int, device, launch_hist=None):
         self.index = index
@@ -429,7 +485,6 @@ class _DeviceState:
         self.last_fuse_key: Optional[tuple] = None
         self.picked: List[Job] = []
         self.ewma_launch_s = 0.0
-        self.ewma_bucket_s: Dict[tuple, float] = {}
         self.jobs = 0
         self.launches = 0
         self.bytes = 0
@@ -438,6 +493,13 @@ class _DeviceState:
         # EWMA mean the dispatcher scores with
         self.launch_hist = launch_hist if launch_hist is not None \
             else metrics_mod.Histogram(f"device{index}/launch_s")
+        self.n_launched = 0        # launch sequence number (manager only)
+        # kind -> phase -> summed seconds of successful launches
+        self.phase_s: Dict[str, Dict[str, float]] = {}
+        self.queue_s = 0.0
+        self.h2d_bytes = 0
+        self.md5_rows = 0
+        self.md5_lane_rows = 0
 
     def load_score(self) -> float:
         return self.pending_s * self.slowdown
@@ -446,9 +508,12 @@ class _DeviceState:
         return {"jobs": self.jobs, "launches": self.launches,
                 "bytes": self.bytes, "interpret": int(self.interpret),
                 "ewma_launch_s": self.ewma_launch_s,
-                "ewma_bucket_s": {f"{k}/{w}": v for (k, w), v
-                                  in self.ewma_bucket_s.items()},
                 "launch_hist": self.launch_hist.summary(),
+                "phase_s": {k: dict(v) for k, v in self.phase_s.items()},
+                "queue_s": self.queue_s,
+                "h2d_bytes": self.h2d_bytes,
+                "md5_rows": self.md5_rows,
+                "md5_lane_rows": self.md5_lane_rows,
                 "queue_depth": self.queue.depth(),
                 "queued_bytes": self.queued_bytes,
                 "pending_s": self.pending_s,
@@ -542,6 +607,7 @@ class CrystalTPU:
         self.running: List[Job] = []  # guarded by self._lock
         self._lock = threading.Lock()
         self._rr = 0  # guarded by self._lock
+        self._seq = itertools.count()
         self.metrics = metrics_mod.MetricsRegistry()
         # atomic counters: manager threads and submitters bump these
         # concurrently; reads keep the old plain-dict shape
@@ -601,6 +667,7 @@ class CrystalTPU:
             raise ValueError(f"unknown lane {lane!r}")
         job = self._make_job(kind, np.asarray(data), meta or {},
                              callback, lane)
+        job.seq = next(self._seq)
         plan = self._shard_plan(job)
         if plan is not None:
             return self._submit_sharded(job, plan)
@@ -746,6 +813,7 @@ class CrystalTPU:
                 child = self._make_job(parent.kind, flat[a:b],
                                        dict(parent.meta), child_cb(i),
                                        parent.lane)
+            child.seq = parent.seq
             children.append(child)
         self.stats.inc("sharded_jobs")
         self.stats.inc("shards", k)
@@ -942,6 +1010,8 @@ class CrystalTPU:
             if self._fault_hook is not None:
                 self._fault_hook(dev.index, batch)
             slot = self._get_slot()
+            launch = _Launch(job.kind, dev.index, dev.n_launched, batch)
+            dev.n_launched += 1
             wall0 = time.perf_counter()
             failed = False
             try:
@@ -950,19 +1020,20 @@ class CrystalTPU:
                 if self._launch_hook is not None:
                     self._launch_hook(dev.index, batch)
                 if job.kind == "direct":
-                    self._execute_direct(dev, slot, batch)
+                    self._execute_direct(dev, slot, batch, launch)
                 else:
-                    self._execute_stream_batch(dev, slot, batch)
+                    self._execute_stream_batch(dev, slot, batch, launch)
             except BaseException as e:          # surfaced via wait()
                 failed = True
                 for j in batch:
                     j.error = e
             finally:
                 wall1 = time.perf_counter()
-                self._retire(dev, batch, wall1 - wall0, failed)
-                self._put_slot(slot)
                 for j in batch:
                     j.t_exec0, j.t_exec1 = wall0, wall1
+                self._retire(dev, batch, launch, wall1 - wall0, failed)
+                self._put_slot(slot)
+                for j in batch:
                     j.done.set()
                     if j.callback is not None:
                         try:
@@ -970,11 +1041,12 @@ class CrystalTPU:
                         except Exception:
                             pass
 
-    def _retire(self, dev: _DeviceState, batch: List[Job], wall_s: float,
-                failed: bool):
+    def _retire(self, dev: _DeviceState, batch: List[Job], launch: _Launch,
+                wall_s: float, failed: bool):
         """Credit the backlog clock, feed the cost model + fusion policy
         with the measured launch wall time, and update the per-device
-        latency EWMAs (successful launches only)."""
+        latency EWMA and launch-phase counters (successful launches
+        only)."""
         kind = batch[0].kind
         padded = sum(j.padded_bytes for j in batch)
         if kind == "direct":
@@ -984,7 +1056,6 @@ class CrystalTPU:
         else:
             actual = sum(int(j.data.size) for j in batch)
             n_rows = len(batch)
-        wbucket = max(j.staged_width for j in batch)
         with self._lock:
             for j in batch:
                 if j in self.running:
@@ -999,10 +1070,13 @@ class CrystalTPU:
             oh, spb = self.cost.params(kind)
             self.policy.observe(padded, actual, n_rows, wall_s, oh, spb)
             dev.launch_hist.record(wall_s)
-            key = (kind, wbucket)
-            prev = dev.ewma_bucket_s.get(key)
-            dev.ewma_bucket_s[key] = wall_s if prev is None \
-                else 0.75 * prev + 0.25 * wall_s
+            sums = dev.phase_s.setdefault(kind, dict.fromkeys(PHASES, 0.0))
+            for name, sec in launch.phase_s.items():
+                sums[name] += sec
+            dev.queue_s += sum(j.t_exec0 - j.t_submit for j in batch)
+            dev.h2d_bytes += launch.h2d_bytes
+            dev.md5_rows += launch.md5_rows
+            dev.md5_lane_rows += launch.md5_lane_rows
             dev.ewma_launch_s = wall_s if not dev.ewma_launch_s \
                 else 0.75 * dev.ewma_launch_s + 0.25 * wall_s
             ratio = min(max(wall_s / est, 0.05), 50.0)
@@ -1068,51 +1142,55 @@ class CrystalTPU:
 
     # -- fused direct batch --------------------------------------------
     def _execute_direct(self, dev: _DeviceState, slot: dict,
-                        batch: List[Job]):
-        t0 = time.perf_counter()
-        # stage 1-2: staging + transfer in.  One padded [B, W] batch for
-        # the whole burst; rows are length-bound so zero padding to the
-        # widest row never changes a digest.  B and W are bucketed to
-        # powers of two to bound jit retraces across ragged bursts.
+                        batch: List[Job], launch: _Launch):
+        # One padded [B, W] batch for the whole burst; rows are
+        # length-bound so zero padding to the widest row never changes a
+        # digest.  B and W are bucketed to powers of two to bound jit
+        # retraces across ragged bursts.
         W = max(j.rows.shape[1] for j in batch)
         W = 1 << (max(W, 4) - 1).bit_length()
         n_rows = sum(j.rows.shape[0] for j in batch)
         B = 1 << (max(n_rows, 1) - 1).bit_length()
-        staging = self._staging(slot, (B, W), np.uint8)
-        lens = np.zeros((B,), np.int64)
-        r = 0
+        launch.shape(n_rows, B * W)
+        with launch.phase("stage"):
+            staging = self._staging(slot, (B, W), np.uint8)
+            lens = np.zeros((B,), np.int64)
+            r = 0
+            for j in batch:
+                n, w = j.rows.shape
+                staging[r:r + n, :w] = j.rows
+                lens[r:r + n] = j.lens
+                r += n
+            words = staging.view("<u4") if staging.flags.c_contiguous \
+                else np.ascontiguousarray(staging).view("<u4")
+            lens_w = (lens // 4).astype(np.int32)
+        with launch.phase("put"):
+            dev_words = jax.device_put(words, dev.device)
+            dev_lens = jax.device_put(lens_w, dev.device)
+            self._stage_sync(dev_words)
+        # ONE kernel launch for the fused batch, device-resident
+        with launch.phase("call"):
+            dig = ops.direct_hash_device(dev_words, dev_lens)
+            self._stage_sync(dig)
+        with launch.phase("wait"):          # digests only: 16 B per row
+            host = ops.digest_bytes(dig)
+        with launch.phase("finish"):
+            r = 0
+            for j in batch:
+                n = j.rows.shape[0]
+                j.result = host[r:r + n].copy()
+                r += n
+        launch.h2d_bytes = words.nbytes + lens_w.nbytes
+        launch.md5_rows = n_rows
+        launch.md5_lane_rows = ops.md5_lane_rows(B)
         for j in batch:
-            n, w = j.rows.shape
-            staging[r:r + n, :w] = j.rows
-            lens[r:r + n] = j.lens
-            r += n
-        words = staging.view("<u4") if staging.flags.c_contiguous \
-            else np.ascontiguousarray(staging).view("<u4")
-        dev_words = jax.device_put(words, dev.device)
-        dev_lens = jax.device_put((lens // 4).astype(np.int32),
-                                  dev.device)
-        self._stage_sync(dev_words)
-        t1 = time.perf_counter()
-        # stage 3: ONE kernel launch for the fused batch, device-resident
-        dig = ops.direct_hash_device(dev_words, dev_lens)
-        self._stage_sync(dig)
-        t2 = time.perf_counter()
-        # stage 4: transfer out (digests only — 16 B per row)
-        host = ops.digest_bytes(dig)
-        t3 = time.perf_counter()
-        timings = {"in": t1 - t0, "kernel": t2 - t1, "out": t3 - t2}
-        r = 0
-        for j in batch:
-            n = j.rows.shape[0]
-            j.result = host[r:r + n].copy()
-            j.timings = dict(timings)       # batch-wide stage times
-            r += n
+            j.timings = dict(launch.phase_s)
         self._account(dev, len(batch), int(np.sum(lens)),
                       sum(j.lane == "scrub" for j in batch))
 
     # -- fused streaming batch (sliding / gear) ------------------------
     def _execute_stream_batch(self, dev: _DeviceState, slot: dict,
-                              batch: List[Job]):
+                              batch: List[Job], launch: _Launch):
         """Execute a burst of same-config stream jobs as ONE padded
         [B, L] multi-row kernel launch.  Rows are zero-padded to the
         widest buffer; B and the word width are bucketed to powers of
@@ -1121,44 +1199,48 @@ class CrystalTPU:
         kind = batch[0].kind
         if kind not in ("sliding", "gear"):
             raise ValueError(f"unknown job kind {kind!r}")
-        t0 = time.perf_counter()
         flats = [j.data.reshape(-1).astype(np.uint8, copy=False)
                  for j in batch]
         lens = [f.size for f in flats]
         n_words = (max(max(lens), 1) + 3) // 4
         Wb = 1 << (max(n_words, 4) - 1).bit_length()
         B = 1 << (len(batch) - 1).bit_length()
-        staging = self._staging(slot, (B, Wb), np.uint32)
-        rows_u8 = staging.view(np.uint8).reshape(B, Wb * 4)
-        for i, f in enumerate(flats):
-            rows_u8[i, :f.size] = f
-        dev_words = jax.device_put(staging, dev.device)
-        self._stage_sync(dev_words)
-        t1 = time.perf_counter()
+        launch.shape(len(batch), B * Wb * 4)
+        with launch.phase("stage"):
+            staging = self._staging(slot, (B, Wb), np.uint32)
+            rows_u8 = staging.view(np.uint8).reshape(B, Wb * 4)
+            for i, f in enumerate(flats):
+                rows_u8[i, :f.size] = f
+        with launch.phase("put"):
+            dev_words = jax.device_put(staging, dev.device)
+            self._stage_sync(dev_words)
         if kind == "sliding":
             window = int(batch[0].meta.get("window", 48))
             stride = int(batch[0].meta.get("stride", 4))
             phases = tuple(range(0, 4, stride))
-            out = ops.sliding_hash_batch_device(dev_words, window // 4,
-                                                phases)
-            self._stage_sync(out)
-            t2 = time.perf_counter()
-            host = np.asarray(out)               # [B, R, Wc/128, 128]
-            for i, j in enumerate(batch):
-                n_off = (lens[i] - window) // stride + 1
-                j.result = ops.sliding_finish(host[i], phases, n_off)
+            with launch.phase("call"):
+                out = ops.sliding_hash_batch_device(dev_words, window // 4,
+                                                    phases)
+                self._stage_sync(out)
+            with launch.phase("wait"):
+                host = np.asarray(out)           # [B, R, Wc/128, 128]
+            with launch.phase("finish"):
+                for i, j in enumerate(batch):
+                    n_off = (lens[i] - window) // stride + 1
+                    j.result = ops.sliding_finish(host[i], phases, n_off)
         else:
-            out = ops.gear_hash_batch_device(
-                dev_words, version=int(batch[0].meta.get("version", 1)))
-            self._stage_sync(out)
-            t2 = time.perf_counter()
-            host = np.asarray(out)               # [B, 4, Wc/128, 128]
-            for i, j in enumerate(batch):
-                j.result = ops.gear_finish(host[i], lens[i])
-        t3 = time.perf_counter()
-        timings = {"in": t1 - t0, "kernel": t2 - t1, "out": t3 - t2}
+            with launch.phase("call"):
+                out = ops.gear_hash_batch_device(
+                    dev_words, version=int(batch[0].meta.get("version", 1)))
+                self._stage_sync(out)
+            with launch.phase("wait"):
+                host = np.asarray(out)           # [B, 4, Wc/128, 128]
+            with launch.phase("finish"):
+                for i, j in enumerate(batch):
+                    j.result = ops.gear_finish(host[i], lens[i])
+        launch.h2d_bytes = staging.nbytes
         for j in batch:
-            j.timings = dict(timings)       # batch-wide stage times
+            j.timings = dict(launch.phase_s)
         self._account(dev, len(batch), int(sum(lens)),
                       sum(j.lane == "scrub" for j in batch))
 

@@ -58,6 +58,7 @@ Configurations mirror the paper's evaluation matrix:
 from __future__ import annotations
 
 import hashlib
+import itertools
 import os
 import queue
 import threading
@@ -73,7 +74,7 @@ from repro.core import crystal as crystal_mod
 from repro.core import integrity
 from repro.core.castore import BlockMeta, MetadataManager, NodeFailure
 from repro.core.crystal import CrystalTPU
-from repro.obs import HeartbeatBoard, MetricsRegistry, Trace
+from repro.obs import HeartbeatBoard, MetricsRegistry, Trace, span
 
 
 @dataclass
@@ -107,6 +108,12 @@ class SAIConfig:
 
 @dataclass
 class WriteStats:
+    """What one write did.  ``stage_s`` holds the seconds of its three
+    stages, ``chunk``, ``hash`` and ``store``, and of parts of them:
+    ``fingerprint`` (getting the CDC fingerprints: the engine job, or
+    the host computation on the cpu hasher) and ``select`` (choosing
+    boundaries from them), both inside ``chunk``; ``pack`` (packing
+    chunks into padded rows and submitting them), inside ``hash``."""
     total_bytes: int = 0
     new_bytes: int = 0
     new_blocks: int = 0
@@ -203,9 +210,11 @@ class _HashHandle:
     whose digests are materialized in submission order on wait()."""
 
     def __init__(self, jobs: Optional[List[crystal_mod.Job]] = None,
-                 digests: Optional[List[bytes]] = None):
+                 digests: Optional[List[bytes]] = None,
+                 pack_s: float = 0.0):
         self._jobs = jobs or []
         self._digests = digests
+        self.pack_s = pack_s          # seconds spent packing the rows
 
     def wait(self) -> List[bytes]:
         if self._digests is None:
@@ -247,6 +256,7 @@ class SAI:
         self.manager = manager
         self.cfg = config
         self.crystal = crystal
+        self._writes = itertools.count()    # write sequence number
         # block-level LRU read cache (digest -> verified bytes), active
         # when cfg.read_cache_bytes > 0; hits skip fetch AND re-verify
         # (entries are inserted only after a digest check passed)
@@ -291,8 +301,12 @@ class SAI:
     def _pack_chunks(self, chunks: List[bytes]):
         return pack_blocks(chunks)
 
-    def _submit_hash(self, chunks: List[bytes]) -> _HashHandle:
+    def _submit_hash(self, chunks: List[bytes],
+                     trace: Optional[Trace] = None,
+                     write: Optional[int] = None) -> _HashHandle:
         """Start hashing ``chunks``; non-blocking on the tpu path.
+        Each group's packing and submission is one ``sai/pack`` span
+        (carrying ``write`` and the submitted job's ``seq``).
 
         A whale submission (total bytes past twice the engine's shard
         threshold) splits into contiguous chunk groups packed and
@@ -310,12 +324,18 @@ class SAI:
             return _HashHandle(digests=[block_digest_cpu(c)
                                         for c in chunks])
         eng = self.engine
+        ids = {} if write is None else {"write": write}
         jobs = []
+        pack_s = 0.0
         for lo, hi in self._shard_groups(chunks, eng):
-            rows, lens = self._pack_chunks(chunks[lo:hi])
-            jobs.append(eng.submit("direct", rows, {"lens": lens},
-                                   lane=self.cfg.lane))
-        return _HashHandle(jobs=jobs)
+            with span("sai/pack", trace, **ids) as sp:
+                rows, lens = self._pack_chunks(chunks[lo:hi])
+                job = eng.submit("direct", rows, {"lens": lens},
+                                 lane=self.cfg.lane)
+                sp.set(seq=job.seq)
+            jobs.append(job)
+            pack_s += sp.duration_s
+        return _HashHandle(jobs=jobs, pack_s=pack_s)
 
     @staticmethod
     def _shard_groups(chunks: List[bytes], eng) -> List[tuple]:
@@ -344,8 +364,20 @@ class SAI:
     def _hash_chunks(self, chunks: List[bytes]) -> List[bytes]:
         return self._submit_hash(chunks).wait()
 
-    def _boundaries(self, data: bytes) -> List[int]:
+    def _chunk(self, data: bytes, times: Dict[str, float],
+               trace: Optional[Trace] = None,
+               write: Optional[int] = None) -> List[bytes]:
+        """Split ``data`` into chunks (the chunk stage), recording the
+        ``fingerprint`` and ``select`` seconds into ``times``."""
+        bounds = self._boundaries(data, times, trace, write)
+        with span("chunk/split", trace, write=write):
+            return chunking.split_chunks(data, bounds)
+
+    def _boundaries(self, data: bytes, times: Dict[str, float],
+                    trace: Optional[Trace] = None,
+                    write: Optional[int] = None) -> List[int]:
         cfg = self.cfg
+        times["fingerprint"] = times["select"] = 0.0
         if len(data) == 0:
             return []
         if cfg.ca == "fixed":
@@ -353,31 +385,31 @@ class SAI:
             return [min((i + 1) * cfg.block_size, len(data))
                     for i in range(n)]
         if cfg.ca == "cdc":
-            if cfg.hasher == "tpu":
-                job = self.engine.submit(
-                    "sliding", np.frombuffer(data, np.uint8),
-                    {"window": cfg.window, "stride": cfg.stride},
-                    lane=cfg.lane)
-                hashes = job.wait()
-            else:
-                hashes = _cpu_sliding(data, cfg.window, cfg.stride)
-            return chunking.select_boundaries(
-                hashes, len(data), window=cfg.window, stride=cfg.stride,
+            window, stride = cfg.window, cfg.stride
+            kind, meta = "sliding", {"window": window, "stride": stride}
+        elif cfg.ca == "cdc-gear":
+            window = stride = 1
+            kind, meta = "gear", {}
+        else:
+            raise ValueError(self.cfg.ca)
+        # waits on the engine: timed, but no span (the manager thread's
+        # phase spans show the work)
+        t0 = time.perf_counter()
+        if cfg.hasher == "tpu":
+            hashes = self.engine.submit(kind, np.frombuffer(data, np.uint8),
+                                        meta, lane=cfg.lane).wait()
+        elif kind == "sliding":
+            hashes = _cpu_sliding(data, window, stride)
+        else:
+            hashes = _cpu_gear(data)
+        times["fingerprint"] = time.perf_counter() - t0
+        with span("chunk/select", trace, write=write) as sp:
+            bounds = chunking.select_boundaries(
+                hashes, len(data), window=window, stride=stride,
                 avg_chunk=cfg.avg_chunk, min_chunk=cfg.min_chunk,
                 max_chunk=cfg.max_chunk)
-        if cfg.ca == "cdc-gear":
-            if cfg.hasher == "tpu":
-                job = self.engine.submit(
-                    "gear", np.frombuffer(data, np.uint8), {},
-                    lane=cfg.lane)
-                hashes = job.wait()
-            else:
-                hashes = _cpu_gear(data)
-            return chunking.select_boundaries(
-                hashes, len(data), window=1, stride=1,
-                avg_chunk=cfg.avg_chunk, min_chunk=cfg.min_chunk,
-                max_chunk=cfg.max_chunk)
-        raise ValueError(self.cfg.ca)
+        times["select"] = sp.duration_s
+        return bounds
 
     # ------------------------------------------------------------------
     # store stage (shared by sync write, async pipeline, checkpointer)
@@ -512,17 +544,20 @@ class SAI:
         cfg = self.cfg
         if cfg.ca == "none":
             return self._write_raw(path, data)
+        write = next(self._writes)
         stats = WriteStats(total_bytes=len(data))
+        times: Dict[str, float] = {}
         t0 = time.perf_counter()
-        bounds = self._boundaries(data)
-        chunks = chunking.split_chunks(data, bounds)
+        chunks = self._chunk(data, times, write=write)
         t1 = time.perf_counter()
-        digests = self._submit_hash(chunks).wait()
+        handle = self._submit_hash(chunks, write=write)
+        digests = handle.wait()
         t2 = t1 if cfg.hasher == "infinite" else time.perf_counter()
-        self._store_chunks(path, len(data), chunks, digests, stats)
-        t3 = time.perf_counter()
+        with span("sai/store", write=write) as st:
+            self._store_chunks(path, len(data), chunks, digests, stats)
         stats.stage_s = {"chunk": t1 - t0, "hash": t2 - t1,
-                         "store": t3 - t2}
+                         "store": st.duration_s, **times,
+                         "pack": handle.pack_s}
         return stats
 
     def write_async(self, path: str, data: bytes,
@@ -535,12 +570,14 @@ class SAI:
         versioning is identical to sequential sync writes.
 
         ``trace`` (an ``obs.Trace``) rides the pipeline queues and
-        collects sai/chunk, sai/hash, sai/store, engine queue/launch,
-        and wal/commit spans."""
+        collects sai/chunk, chunk/select, chunk/split, sai/hash,
+        sai/pack, sai/store, engine queue/launch, and wal/commit
+        spans."""
         fut = WriteFuture()
+        write = next(self._writes)
         with self._pipe_lock:
             self._ensure_pipeline()
-            self._chunk_q.put((fut, path, bytes(data), trace))  # ra: disable=RA04(unbounded queue: put cannot block; hoisting it would race close)
+            self._chunk_q.put((fut, path, bytes(data), trace, write))  # ra: disable=RA04(unbounded queue: put cannot block; hoisting it would race close)
         return fut
 
     def flush(self):
@@ -609,24 +646,27 @@ class SAI:
                 chunk_q.task_done()
                 return                   # heartbeat stays parked
             hb.beat()
-            fut, path, data, trace = item
+            fut, path, data, trace, write = item
             # per-path lane: commits for one path stay FIFO while
             # different paths commit on parallel lanes
             store_q = store_qs[hash(path) % len(store_qs)]
             try:
                 if self.cfg.ca == "none":
-                    store_q.put((fut, path, data, None, None, {}, trace))
+                    store_q.put((fut, path, data, None, None, {}, trace,
+                                 write))
                     continue
+                times: Dict[str, float] = {}
                 t0 = time.perf_counter()
-                bounds = self._boundaries(data)
-                chunks = chunking.split_chunks(data, bounds)
+                chunks = self._chunk(data, times, trace, write)
                 t1 = time.perf_counter()
                 if trace is not None:
                     trace.add_span("sai/chunk", t0, t1,
                                    chunks=len(chunks))
-                handle = self._submit_hash(chunks)   # non-blocking (tpu)
-                store_q.put((fut, path, data, chunks, handle,
-                             {"chunk": t1 - t0, "t_hash0": t1}, trace))
+                # non-blocking (tpu)
+                handle = self._submit_hash(chunks, trace, write)
+                times.update(chunk=t1 - t0, t_hash0=t1, pack=handle.pack_s)
+                store_q.put((fut, path, data, chunks, handle, times, trace,
+                             write))
             except BaseException as e:
                 fut._fail(e)
             finally:
@@ -641,26 +681,25 @@ class SAI:
                 store_q.task_done()
                 return
             hb.beat()
-            fut, path, data, chunks, handle, times, trace = item
+            fut, path, data, chunks, handle, times, trace, write = item
             try:
                 if handle is None:                   # ca='none'
                     fut._resolve(self._write_raw(path, data))
                     continue
                 stats = WriteStats(total_bytes=len(data))
-                digests = handle.wait()
+                t_hash0 = times.pop("t_hash0")
+                digests = handle.wait()             # timed, no span
                 t2 = time.perf_counter()
                 if trace is not None:
-                    trace.add_span("sai/hash", times["t_hash0"], t2)
+                    trace.add_span("sai/hash", t_hash0, t2)
                     _trace_engine_jobs(trace, handle)
-                self._store_chunks(path, len(data), chunks, digests,
-                                   stats, trace=trace)
-                t3 = time.perf_counter()
-                if trace is not None:
-                    trace.add_span("sai/store", t2, t3)
+                with span("sai/store", trace, write=write) as st:
+                    self._store_chunks(path, len(data), chunks, digests,
+                                       stats, trace=trace)
                 hash_s = 0.0 if self.cfg.hasher == "infinite" \
-                    else t2 - times["t_hash0"]
-                stats.stage_s = {"chunk": times["chunk"],
-                                 "hash": hash_s, "store": t3 - t2}
+                    else t2 - t_hash0
+                stats.stage_s = {"hash": hash_s, "store": st.duration_s,
+                                 **times}
                 fut._resolve(stats)
             except BaseException as e:
                 fut._fail(e)

@@ -43,6 +43,12 @@ from repro.kernels import sliding_md5 as slide_k
 # --------------------------------------------------------------------------
 
 
+def md5_lane_rows(n_rows: int) -> int:
+    """Rows the MD5 kernel computes for ``n_rows`` messages: one per
+    lane, padded up to a whole lane tile."""
+    return -(-n_rows // md5_k.TILE_N) * md5_k.TILE_N
+
+
 @jax.jit
 def direct_hash_device(words: jax.Array, lens_w: jax.Array) -> jax.Array:
     """Device-resident direct hashing: ``words`` [N, W] uint32 already on
@@ -50,7 +56,7 @@ def direct_hash_device(words: jax.Array, lens_w: jax.Array) -> jax.Array:
     [N, 4] uint32 digest array *on device* (callers pull it with
     ``digest_bytes`` — 16 B/row, the only host transfer)."""
     N, W = words.shape
-    n_pad = (-N) % md5_k.TILE_N
+    n_pad = md5_lane_rows(N) - N
     # bound the chunk grid to ~8 steps per segment tile (grid dispatch
     # dominates on the interpreter; on TPU this is simply a larger VMEM
     # message block, capped at 16*512*TILE_N words = 4 MiB)

@@ -7,7 +7,7 @@ trace span hierarchy, and health verdict rules.
 """
 
 from .metrics import Counter, CounterGroup, Gauge, Histogram, MetricsRegistry
-from .trace import Span, Trace, Tracer
+from .trace import Span, Trace, Tracer, span
 from .export import dump_slow_log, flatten, prometheus_text, truncate_tree
 from .health import (
     Heartbeat,
@@ -30,6 +30,7 @@ __all__ = [
     "Span",
     "Trace",
     "Tracer",
+    "span",
     "dump_slow_log",
     "flatten",
     "prometheus_text",

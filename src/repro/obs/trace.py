@@ -15,10 +15,18 @@ which benchmarks dump to ``obs-slowlog.json`` for the CI artifact.
 
 All timestamps are ``time.perf_counter()`` — monotonic, comparable
 only within a process, which is all span nesting needs.
+
+``span`` is the one helper the program's hot paths use: it times a
+piece of work, attaches it to a request ``Trace`` when one rides
+along, and, while the JAX profiler is tracing, also records it as a
+``jax.profiler.TraceAnnotation`` on the profiler's host plane, whose
+clock the device planes share.  The profiler is looked up only where
+JAX is already loaded, so this module stays importable without JAX.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from collections import deque
@@ -127,3 +135,64 @@ class Tracer:
                 "slow": self._slow_count,
                 "slow_threshold_s": self.slow_threshold_s,
             }
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` where this process has loaded
+    JAX, else None: a process that never imported JAX runs no profiler,
+    and a span never imports it."""
+    prof = sys.modules.get("jax.profiler")
+    return None if prof is None else prof.TraceAnnotation
+
+
+def _format(ids: Dict) -> Dict:
+    """Profiler metadata: a list or tuple of ids becomes one
+    space-separated string (the profiler's metadata has no lists)."""
+    return {k: " ".join(map(str, v)) if isinstance(v, (list, tuple)) else v
+            for k, v in ids.items()}
+
+
+class span:
+    """Time one piece of work: ``with span("engine/put:direct", trace,
+    device=0) as sp: ...``, then ``sp.duration_s``.
+
+    While the JAX profiler is tracing, the span is also a
+    ``TraceAnnotation`` carrying ``ids`` as metadata; otherwise it
+    creates no profiler event and formats no metadata.  When ``trace``
+    (an ``obs.Trace``) is given, the span is added to it on exit, with
+    ``ids`` as its meta.  ``set`` adds ids learnt inside the span."""
+
+    __slots__ = ("name", "trace", "ids", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, trace: Optional[Trace] = None,
+                 **ids) -> None:
+        self.name = name
+        self.trace = trace
+        self.ids = ids
+        self.t0 = self.t1 = 0.0
+        self._ann = None
+
+    def __enter__(self) -> "span":
+        ann = _annotation()
+        if ann is not None and ann.is_enabled():
+            self._ann = ann(self.name, **_format(self.ids))
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def set(self, **ids) -> None:
+        self.ids.update(ids)
+        if self._ann is not None:
+            self._ann.set_metadata(**_format(ids))
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        if self.trace is not None:
+            self.trace.add_span(self.name, self.t0, self.t1, **self.ids)
+
+    @property
+    def duration_s(self) -> float:
+        return self.t1 - self.t0

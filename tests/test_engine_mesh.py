@@ -250,8 +250,8 @@ def test_per_device_stats_and_queue_depth():
         assert set(st["per_device"]) == {0, 1}
         for row in st["per_device"].values():
             for key in ("jobs", "launches", "bytes", "ewma_launch_s",
-                        "ewma_bucket_s", "queue_depth", "queued_bytes",
-                        "slowdown", "manager_restarts"):
+                        "queue_depth", "queued_bytes", "slowdown",
+                        "manager_restarts"):
                 assert key in row, key
         assert sum(d["jobs"] for d in st["per_device"].values()) == 4
         assert "policy" in st and "cost_model" in st
@@ -262,6 +262,28 @@ def test_per_device_stats_and_queue_depth():
             eng.queue_depth(device=7)
     finally:
         eng.shutdown()
+
+
+@pytest.mark.parametrize("shard_min_bytes", [8 << 20, 32 << 10])
+def test_launch_counters_sum_over_the_mesh(shard_min_bytes):
+    """Row and lane counters add up across the mesh whether a job runs
+    whole or in shards; shard children keep their parent's seq."""
+    rows = np.random.default_rng(2).integers(0, 256, (16, 8192), np.uint8)
+    eng = _mesh(4, shard_min_bytes=shard_min_bytes)
+    try:
+        job = eng.submit("direct", rows, {})
+        job.wait()
+        st = eng.snapshot_stats()
+    finally:
+        eng.shutdown()
+    per = [d for d in st["per_device"].values() if d["launches"]]
+    assert sum(d["md5_rows"] for d in per) == 16
+    assert all(d["md5_lane_rows"] % 128 == 0 for d in per)
+    assert sum(d["bytes"] for d in per) == 16 * 8192
+    assert sum(d["h2d_bytes"] for d in per) >= 16 * 8192
+    assert sum(d["launches"] for d in per) == max(st["shards"], 1)
+    assert job.seq >= 0 and set(job.timings) == set(
+        next(iter(per))["phase_s"]["direct"])
 
 
 # ---------------------------------------------------------------------
