@@ -28,16 +28,15 @@ def run() -> list:
     lens = jnp.full((segs.shape[0],), segs.shape[1], jnp.int32)
 
     batch = words[None]                # B=1 row of the fused entry points
+    rows = jnp.asarray(buf.reshape(1, -1, 128))     # gear: one byte each
     cases = [
         ("sliding_md5_stride1", sliding_hash_batch_device.lower(
             batch, w_words=12, phases=(0, 1, 2, 3))),
         ("sliding_md5_stride4", sliding_hash_batch_device.lower(
             batch, w_words=12, phases=(0,))),
-        ("gear_v1", gear_hash_batch_device.lower(batch, version=1)),
-        ("gear_v2_doubling", gear_hash_batch_device.lower(batch,
-                                                           version=2)),
-        ("gear_v3_hybrid", gear_hash_batch_device.lower(batch,
-                                                         version=3)),
+        ("gear_v1", gear_hash_batch_device.lower(rows, version=1)),
+        ("gear_v2_doubling", gear_hash_batch_device.lower(rows, version=2)),
+        ("gear_v3_hybrid", gear_hash_batch_device.lower(rows, version=3)),
         ("direct_md5_4k", direct_hash_device.lower(segs, lens)),
     ]
     for name, lowered in cases:

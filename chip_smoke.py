@@ -100,10 +100,11 @@ def phase_mosaic(dev):
     from repro.kernels import ops
     words = jax.device_put(np.zeros((8, 2048), np.uint32), dev)
     lens = jax.device_put(np.full((8,), 2048, np.int32), dev)
+    rows = jax.device_put(np.zeros((8, 64, 128), np.uint8), dev)
     lowered = {
         "direct": ops.direct_hash_device.lower(words, lens),
         "sliding": ops.sliding_hash_batch_device.lower(words, 12, (0,)),
-        "gear": ops.gear_hash_batch_device.lower(words, version=1),
+        "gear": ops.gear_hash_batch_device.lower(rows),
     }
     for name, low in lowered.items():
         check("tpu_custom_call" in low.compile().as_text(),

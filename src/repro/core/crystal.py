@@ -76,8 +76,9 @@ and the gateway read it).
 Launch phases: a launch runs five phases on its manager thread —
 ``stage`` (zero and fill the staging matrix), ``put`` (``device_put``
 of the inputs), ``call`` (the jitted ``ops.*_device`` call), ``wait``
-(the blocking pull of the result) and ``finish`` (``gear_finish`` /
-``sliding_finish``, or slicing digests into each job).  Each phase is
+(the blocking pull of the result) and ``finish`` (``gear_finish``, a
+reshape and a slice of the byte-order fingerprints, ``sliding_finish``,
+or slicing digests into each job).  Each phase is
 one ``repro.obs.span`` named ``engine/<phase>:<kind>`` (on the JAX
 profiler's clock while it traces, carrying ``device``, ``launch``,
 ``rows``, ``padded_bytes`` and the jobs' ``seq``), and its one timing
@@ -113,7 +114,9 @@ Job normal forms
               length-bound, so trailing zeros never change them).
   'sliding' : data = flat uint8 buffer, meta {'window', 'stride'};
               result [n_offsets] uint32 window hashes.
-  'gear'    : data = flat uint8 buffer; result [len] uint32 rolling hash.
+  'gear'    : data = flat uint8 buffer, meta {'version'} (default
+              ``ops.GEAR_VERSION``); result [len] uint32 rolling hash, a
+              view of the fingerprints pulled from the device.
 """
 from __future__ import annotations
 
@@ -703,8 +706,8 @@ class CrystalTPU:
                                 int(job.meta.get("window", 48)),
                                 int(job.meta.get("stride", 4)), octave)
             else:
-                job.fuse_key = ("gear", int(job.meta.get("version", 1)),
-                                octave)
+                job.fuse_key = ("gear", int(job.meta.get(
+                    "version", ops.GEAR_VERSION)), octave)
             n_words = (max(job.data.size, 1) + 3) // 4
             job.staged_width = 4 << (max(n_words, 4) - 1).bit_length()
         else:
@@ -1195,7 +1198,8 @@ class CrystalTPU:
         [B, L] multi-row kernel launch.  Rows are zero-padded to the
         widest buffer; B and the word width are bucketed to powers of
         two to bound retraces across ragged bursts.  Each job's hashes
-        are sliced out of the fused phase-matrix output."""
+        are sliced out of the fused output: sliding's phase matrix is
+        interleaved on the host, gear's byte-order rows are viewed."""
         kind = batch[0].kind
         if kind not in ("sliding", "gear"):
             raise ValueError(f"unknown job kind {kind!r}")
@@ -1203,7 +1207,8 @@ class CrystalTPU:
                  for j in batch]
         lens = [f.size for f in flats]
         n_words = (max(max(lens), 1) + 3) // 4
-        Wb = 1 << (max(n_words, 4) - 1).bit_length()
+        # at least one 128-byte row, so a gear row reshapes to [R, 128]
+        Wb = 1 << (max(n_words, layout.LANES // 4) - 1).bit_length()
         B = 1 << (len(batch) - 1).bit_length()
         launch.shape(len(batch), B * Wb * 4)
         with launch.phase("stage"):
@@ -1211,15 +1216,18 @@ class CrystalTPU:
             rows_u8 = staging.view(np.uint8).reshape(B, Wb * 4)
             for i, f in enumerate(flats):
                 rows_u8[i, :f.size] = f
+        # sliding takes packed words, gear one byte per element
+        host_in = staging if kind == "sliding" \
+            else rows_u8.reshape(B, -1, layout.LANES)
         with launch.phase("put"):
-            dev_words = jax.device_put(staging, dev.device)
-            self._stage_sync(dev_words)
+            dev_in = jax.device_put(host_in, dev.device)
+            self._stage_sync(dev_in)
         if kind == "sliding":
             window = int(batch[0].meta.get("window", 48))
             stride = int(batch[0].meta.get("stride", 4))
             phases = tuple(range(0, 4, stride))
             with launch.phase("call"):
-                out = ops.sliding_hash_batch_device(dev_words, window // 4,
+                out = ops.sliding_hash_batch_device(dev_in, window // 4,
                                                     phases)
                 self._stage_sync(out)
             with launch.phase("wait"):
@@ -1231,10 +1239,11 @@ class CrystalTPU:
         else:
             with launch.phase("call"):
                 out = ops.gear_hash_batch_device(
-                    dev_words, version=int(batch[0].meta.get("version", 1)))
+                    dev_in, version=int(batch[0].meta.get(
+                        "version", ops.GEAR_VERSION)))
                 self._stage_sync(out)
             with launch.phase("wait"):
-                host = np.asarray(out)           # [B, 4, Wc/128, 128]
+                host = np.asarray(out)           # [B, R, 128], byte order
             with launch.phase("finish"):
                 for i, j in enumerate(batch):
                     j.result = ops.gear_finish(host[i], lens[i])

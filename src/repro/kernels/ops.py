@@ -16,14 +16,16 @@ Two layers are exposed:
     buffers and ``device_put`` so data stays on the accelerator from
     transfer through kernel with no host round-trip.
 
-Stream batching: ``sliding_hash_batch_device`` / ``gear_hash_batch_device``
-take a padded [B, L] word matrix (B independent buffers) and execute the
-whole batch as ONE kernel launch — the engine fuses bursts of same-config
-stream jobs through these, then slices each job's rows out of the fused
-phase-matrix output host-side (``sliding_finish`` / ``gear_finish`` per
-row).  Rows are zero-padded to the widest buffer in the batch; window
-hashes only ever read bytes inside their own job's valid prefix, so
-padding never changes a returned hash.
+Stream batching: ``sliding_hash_batch_device`` takes a padded [B, L]
+word matrix and ``gear_hash_batch_device`` a padded [B, R, 128] byte
+matrix (B independent buffers, one byte per element for gear); each
+executes the whole batch as ONE kernel launch — the engine fuses bursts
+of same-config stream jobs through these, then takes each job's row of
+the fused output host-side: ``sliding_finish`` interleaves the sliding
+kernel's phase matrix, ``gear_finish`` only views the gear kernel's
+byte-order output.  Rows are zero-padded to the widest buffer in the
+batch; window hashes only ever read bytes inside their own job's valid
+prefix, so padding never changes a returned hash.
 """
 from __future__ import annotations
 
@@ -37,6 +39,7 @@ import numpy as np
 from repro.kernels import gear as gear_k
 from repro.kernels import md5 as md5_k
 from repro.kernels import sliding_md5 as slide_k
+from repro.kernels.layout import LANES
 
 # --------------------------------------------------------------------------
 # direct hashing
@@ -118,12 +121,12 @@ def hash_blocks(data: bytes, block_bytes: int) -> Tuple[np.ndarray, bytes]:
 # --------------------------------------------------------------------------
 # sliding-window MD5 (paper-faithful CDC)
 # --------------------------------------------------------------------------
-def _pick_tile(L: int, base: int) -> int:
+def _pick_tile(L: int, base: int, cap: int = 1 << 15) -> int:
     """Tile width bounding grid steps to ~64 (VMEM stays < ~0.5 MB/input
     block; the interpreter pays per grid step, so step count dominates
     its run time on CPU)."""
     t = base
-    while L // t > 64 and t < (1 << 15):
+    while L // t > 64 and t < cap:
         t *= 2
     return t
 
@@ -213,48 +216,60 @@ def sliding_window_hash(data: bytes | np.ndarray, window: int = 48,
 # --------------------------------------------------------------------------
 # gear rolling hash (beyond-paper CDC)
 # --------------------------------------------------------------------------
-def gear_hash_device(words: jax.Array, version: int = 1) -> jax.Array:
-    """Device-resident gear hashing: ``words`` [L] uint32 on the target
-    device.  Returns the [4, w_cap/128, 128] uint32 phase matrix on
-    device; ``gear_finish`` flattens it host-side.  (The B=1 case of the
+# the tap construction the engine launches: the hybrid one took the least
+# kernel time per 64 MiB on a TPU v5e
+GEAR_VERSION = 3
+
+
+def gear_hash_device(data: jax.Array,
+                     version: int = GEAR_VERSION) -> jax.Array:
+    """Device-resident gear hashing: ``data`` [R, 128] uint8 on the
+    target device.  Returns the [R', 128] uint32 hashes on device, in
+    byte order (R' >= R: rows padded to the kernel's tile);
+    ``gear_finish`` flattens them host-side.  (The B=1 case of the
     batched path — one pad/launch wrapper and one jit cache.)"""
-    return gear_hash_batch_device(words[None], version=version)[0]
+    return gear_hash_batch_device(data[None], version=version)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("version",))
-def gear_hash_batch_device(words: jax.Array, version: int = 1) -> jax.Array:
-    """Fused multi-buffer gear hashing: ``words`` [B, L] uint32 on the
-    target device, one row per job (rows zero-padded to the batch width).
-    ONE kernel launch covers the whole batch; returns the
-    [B, 4, Wc/128, 128] uint32 phase matrices on device — callers slice
-    row b and flatten it with ``gear_finish`` using that job's own byte
-    length."""
-    B, L = words.shape
-    T = _pick_tile(L, gear_k.TILE_W)
-    w_cap = ((L + T - 1) // T) * T
-    strip = jnp.pad(words, ((0, 0), (T, w_cap - L)))   # per-row history 0s
-    return gear_k.gear_pallas(strip, version=version, tile=T)
+def gear_hash_batch_device(data: jax.Array,
+                           version: int = GEAR_VERSION) -> jax.Array:
+    """Fused multi-buffer gear hashing: ``data`` [B, R, 128] uint8 on
+    the target device, one job's bytes per row in row-major order (rows
+    zero-padded to the batch width).  ONE kernel launch covers the whole
+    batch; returns [B, R', 128] uint32 hashes on device, byte p of row b
+    at [b, p // 128, p % 128] (R' >= R: zero rows pad R * 128 up to a
+    multiple of the tile, which a power-of-two R of at least 32 never
+    needs) — callers slice row b and flatten it
+    with ``gear_finish`` using that job's own byte length."""
+    B, R, _ = data.shape
+    T = _pick_tile(R * LANES, gear_k.TILE, cap=1 << 17)
+    r_cap = -(-R * LANES // T) * T // LANES
+    if r_cap != R:
+        data = jnp.pad(data, ((0, 0), (0, r_cap - R), (0, 0)))
+    return gear_k.gear_pallas(data, version=version, tile=T)
 
 
 def gear_finish(out: np.ndarray, n_bytes: int) -> np.ndarray:
-    """Flatten the [4, w_cap/128, 128] phase matrix to per-byte order
-    (4q + r)."""
-    return out.reshape(4, -1).T.reshape(-1)[:n_bytes]
+    """The first ``n_bytes`` hashes of one row's [R, 128] output: a
+    reshape and a slice, no copy (the kernel writes byte order)."""
+    return out.reshape(-1)[:n_bytes]
 
 
-def gear_hash(data: bytes | np.ndarray, version: int = 1) -> np.ndarray:
+def gear_hash(data: bytes | np.ndarray,
+              version: int = GEAR_VERSION) -> np.ndarray:
     """Windowed gear hash at every byte position.  Returns [L] uint32.
-    Positions < 32 differ from ref (zero-history convention) — chunking
-    never places boundaries inside the minimum chunk size anyway.
-    ``version=2`` selects the log-doubling kernel (§Perf C2) — identical
-    outputs, ~3x fewer VPU ops."""
+    Positions < 32 differ from ref (zero-history convention: the 32
+    bytes before the stream are zeros, hashed as ``gear(0)``) —
+    chunking never places boundaries inside the minimum chunk size
+    anyway.  ``version`` picks the tap construction (1 direct,
+    2 log-doubling, 3 hybrid) — identical outputs."""
     buf = np.frombuffer(data, np.uint8) if isinstance(data, (bytes,
                                                              bytearray)) \
         else np.asarray(data, np.uint8)
     L = len(buf)
-    pad = (-L) % 4
-    words = jnp.asarray(np.pad(buf, (0, pad)).view("<u4"))
-    out = np.asarray(gear_hash_device(words, version=version))
+    rows = np.pad(buf, (0, -L % LANES)).reshape(-1, LANES)
+    out = np.asarray(gear_hash_device(jnp.asarray(rows), version=version))
     return gear_finish(out, L)
 
 

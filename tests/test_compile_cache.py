@@ -16,7 +16,7 @@ PROBE = textwrap.dedent("""
     path = compile_cache.enable()
     if "--compile" in sys.argv:
         from repro.kernels import ops
-        ops.gear_hash_batch_device(jnp.zeros((1, 4096), jnp.uint32))
+        ops.gear_hash_batch_device(jnp.zeros((1, 32, 128), jnp.uint8))
     print(json.dumps({"path": path,
                       "config": jax.config.jax_compilation_cache_dir}))
 """)

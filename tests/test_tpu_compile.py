@@ -66,7 +66,9 @@ def _lower(one_chip, kind, rows, row_bytes, static):
     if kind == "sliding":
         return ops.sliding_hash_batch_device.lower(
             words, 12, static.get("phases", (0,)))
-    return ops.gear_hash_batch_device.lower(words,
+    data = jax.ShapeDtypeStruct((rows, row_bytes // 128, 128), jnp.uint8,
+                                sharding=one_chip)
+    return ops.gear_hash_batch_device.lower(data,
                                             version=static.get("version", 1))
 
 
