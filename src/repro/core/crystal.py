@@ -97,6 +97,17 @@ prepare word-packed staging buffers, the device buffer is handed straight
 to the jit'd kernel entry points (``ops.*_device``), and only the (small)
 digest/fingerprint output is pulled back to the host.
 
+Device-resident jobs: a ``direct`` job whose data is a ``jax.Array`` on
+one of the engine's devices (a shard of a train state already in HBM)
+is hashed where it lies.  It runs on that device's manager, is never
+moved to another device (crash recovery re-queues it there), never
+fused with other jobs and never sharded as a whale.  Its launch has four
+phases, ``pack`` (``ops.pack_blocks_device`` builds the rows and
+lengths on the device), ``call``, ``wait`` and ``finish``, spanned as
+``engine/<phase>:device``; it hands nothing to ``device_put``, so adds
+nothing to ``h2d_bytes``.  Each device counts its ``device_jobs`` and
+their ``device_bytes``.
+
 TPU/JAX adaptation: JAX's runtime is asynchronous by design, so overlap is
 expressed by *not* synchronizing between stage boundaries (async dispatch
 pipelines transfer and compute), while the no-overlap baseline inserts
@@ -117,6 +128,12 @@ Job normal forms
   'gear'    : data = flat uint8 buffer, meta {'version'} (default
               ``ops.GEAR_VERSION``); result [len] uint32 rolling hash, a
               view of the fingerprints pulled from the device.
+  'direct' on the device: data = a ``jax.Array`` committed to one of
+              the engine's devices (any shape; a dtype
+              ``device_dtype_ok`` takes: bool, 1-, 2- or 4-byte),
+              meta {'block_bytes'}: its bytes cut into fixed blocks
+              (the last one shorter); result [n_blocks, 16] uint8
+              digests, as a host direct job over the same blocks.
 """
 from __future__ import annotations
 
@@ -141,6 +158,7 @@ from repro.roofline.analysis import HASH_OPS_PER_BYTE, hash_cost_seed
 
 LANES = ("fg", "batch", "scrub")       # dequeue priority, highest first
 PHASES = ("stage", "put", "call", "wait", "finish")   # of one launch
+DEVICE_PHASES = ("pack", "call", "wait", "finish")    # of a device job
 
 
 class LaneQueue:
@@ -222,6 +240,9 @@ class Job:                             # numpy fields, and the manager's
     # normalized 'direct' payload (set at submit time)
     rows: Optional[np.ndarray] = None
     lens: Optional[np.ndarray] = None
+    # a device-resident job's bytes (a jax.Array pinned to its device)
+    array: Any = None
+    n_bytes: int = 0
     # jobs with equal fuse keys may share one kernel launch
     fuse_key: tuple = ()
     # 'fg' = interactive client traffic; 'batch' = throughput traffic
@@ -253,6 +274,12 @@ class Job:                             # numpy fields, and the manager's
     @property
     def padded_bytes(self) -> int:
         return self.n_rows * max(self.staged_width, 1)
+
+    @property
+    def launch_kind(self) -> str:
+        """What its launch runs as: ``device`` for a device-resident
+        job, else its kind."""
+        return "device" if self.array is not None else self.kind
 
 
 def _normalize_direct(data: np.ndarray, meta: Dict[str, Any]):
@@ -475,7 +502,8 @@ class _DeviceState:
                  "pending_s", "slowdown", "last_fuse_key", "picked",
                  "ewma_launch_s", "jobs", "launches", "bytes", "restarts",
                  "launch_hist", "n_launched", "phase_s", "queue_s",
-                 "h2d_bytes", "md5_rows", "md5_lane_rows")
+                 "h2d_bytes", "md5_rows", "md5_lane_rows", "device_jobs",
+                 "device_bytes")
 
     def __init__(self, index: int, device, launch_hist=None):
         self.index = index
@@ -503,6 +531,8 @@ class _DeviceState:
         self.h2d_bytes = 0
         self.md5_rows = 0
         self.md5_lane_rows = 0
+        self.device_jobs = 0       # device-resident jobs and their bytes
+        self.device_bytes = 0
 
     def load_score(self) -> float:
         return self.pending_s * self.slowdown
@@ -517,6 +547,8 @@ class _DeviceState:
                 "h2d_bytes": self.h2d_bytes,
                 "md5_rows": self.md5_rows,
                 "md5_lane_rows": self.md5_lane_rows,
+                "device_jobs": self.device_jobs,
+                "device_bytes": self.device_bytes,
                 "queue_depth": self.queue.depth(),
                 "queued_bytes": self.queued_bytes,
                 "pending_s": self.pending_s,
@@ -611,6 +643,8 @@ class CrystalTPU:
         self._lock = threading.Lock()
         self._rr = 0  # guarded by self._lock
         self._seq = itertools.count()
+        # (shape, dtype, device, block) whose block gathers are compiled
+        self._pull_shapes: set = set()  # guarded by self._lock
         self.metrics = metrics_mod.MetricsRegistry()
         # atomic counters: manager threads and submitters bump these
         # concurrently; reads keep the old plain-dict shape
@@ -663,13 +697,18 @@ class CrystalTPU:
         a manager picks it up.  Jobs whose padded staging footprint
         reaches ``shard_min_bytes`` on a >= 2 device mesh are sharded
         into per-device sub-launches (child jobs appear in the stats;
-        the returned parent resolves when all shards do)."""
+        the returned parent resolves when all shards do).  A
+        ``jax.Array`` is a device-resident job (module docstring)."""
         if not self._alive:
             raise RuntimeError("CrystalTPU engine is shut down")
         if lane not in LANES:
             raise ValueError(f"unknown lane {lane!r}")
-        job = self._make_job(kind, np.asarray(data), meta or {},
-                             callback, lane)
+        if isinstance(data, jax.Array):
+            job = self._make_device_job(kind, data, meta or {}, callback,
+                                        lane)
+        else:
+            job = self._make_job(kind, np.asarray(data), meta or {},
+                                 callback, lane)
         job.seq = next(self._seq)
         plan = self._shard_plan(job)
         if plan is not None:
@@ -681,6 +720,32 @@ class CrystalTPU:
         """Submit a stream of jobs back-to-back (the paper's batched
         streaming workload) and return the job list."""
         return [self.submit(kind, b, meta) for b in buffers]
+
+    def _make_device_job(self, kind: str, data: jax.Array,
+                         meta: Dict[str, Any], callback, lane: str) -> Job:
+        """A job over bytes already on one of the engine's devices,
+        pinned to that device's manager."""
+        if kind != "direct":
+            raise ValueError(f"no device-resident {kind!r} jobs")
+        devs = data.devices()
+        index = next((s.index for s in self._dev_states
+                      if devs == {s.device}), None)
+        if index is None:
+            raise ValueError(f"a device-resident job must lie on one of "
+                             f"the engine's devices, not on {devs}")
+        if not device_dtype_ok(data.dtype):
+            raise ValueError(f"no device-resident job over {data.dtype} "
+                             f"(hash a host copy)")
+        block = int(meta["block_bytes"])
+        n_bytes = int(data.size) * data.dtype.itemsize
+        if block % 4 or n_bytes == 0:
+            raise ValueError(f"{n_bytes} bytes in {block}-byte blocks")
+        job = Job(kind=kind, meta=meta, callback=callback, lane=lane,
+                  array=data, n_bytes=n_bytes, device_index=index)
+        job.fuse_key = ("device", id(job))              # never fuses
+        job.n_rows = -(-n_bytes // block)
+        job.staged_width = ops.row_bytes(min(block, n_bytes))
+        return job
 
     def _make_job(self, kind: str, data: np.ndarray, meta: Dict[str, Any],
                   callback, lane: str, rows: Optional[np.ndarray] = None,
@@ -727,9 +792,12 @@ class CrystalTPU:
         disables the affinity pull (shard children were split to run on
         *different* devices — affinity would fuse them right back)."""
         with self._lock:
-            job.cost_est = self.cost.estimate(job.kind, job.padded_bytes)
+            job.cost_est = self.cost.estimate(job.launch_kind,
+                                              job.padded_bytes)
             states = self._dev_states
             cands = [s for s in states if s.index != exclude] or states
+            if job.array is not None:           # never leaves its device
+                cands = [states[job.device_index]]
             if not cands:
                 self.outstanding.put(job, lane=job.lane)
                 return job
@@ -759,7 +827,7 @@ class CrystalTPU:
     # ------------------------------------------------------------------
     def _shard_plan(self, job: Job):
         """Per-device sub-launch plan for a whale job, or None."""
-        if len(self._dev_states) < 2:
+        if len(self._dev_states) < 2 or job.array is not None:
             return None
         padded = job.padded_bytes
         if padded < self.shard_min_bytes:
@@ -941,8 +1009,8 @@ class CrystalTPU:
         carry) where carry is a non-fusable job that was popped and must
         be executed next."""
         batch = [first]
-        if not (self.coalesce and first.kind in ("direct", "sliding",
-                                                 "gear")):
+        if not (self.coalesce and first.array is None
+                and first.kind in ("direct", "sliding", "gear")):
             return batch, None
         rows, width = first.n_rows, first.staged_width
         max_rows = self.policy.cur_rows
@@ -1013,7 +1081,8 @@ class CrystalTPU:
             if self._fault_hook is not None:
                 self._fault_hook(dev.index, batch)
             slot = self._get_slot()
-            launch = _Launch(job.kind, dev.index, dev.n_launched, batch)
+            launch = _Launch(job.launch_kind, dev.index, dev.n_launched,
+                             batch)
             dev.n_launched += 1
             wall0 = time.perf_counter()
             failed = False
@@ -1022,7 +1091,9 @@ class CrystalTPU:
                     self.running.extend(batch)
                 if self._launch_hook is not None:
                     self._launch_hook(dev.index, batch)
-                if job.kind == "direct":
+                if job.array is not None:
+                    self._execute_device(dev, job, launch)
+                elif job.kind == "direct":
                     self._execute_direct(dev, slot, batch, launch)
                 else:
                     self._execute_stream_batch(dev, slot, batch, launch)
@@ -1050,9 +1121,12 @@ class CrystalTPU:
         with the measured launch wall time, and update the per-device
         latency EWMA and launch-phase counters (successful launches
         only)."""
-        kind = batch[0].kind
+        kind = launch.kind
         padded = sum(j.padded_bytes for j in batch)
-        if kind == "direct":
+        if kind == "device":
+            actual = sum(j.n_bytes for j in batch)
+            n_rows = sum(j.n_rows for j in batch)
+        elif kind == "direct":
             actual = sum(int(j.lens.sum()) for j in batch
                          if j.lens is not None)
             n_rows = sum(j.n_rows for j in batch)
@@ -1066,14 +1140,16 @@ class CrystalTPU:
                 if j in dev.picked:
                     dev.picked.remove(j)
                 dev.pending_s = max(dev.pending_s - j.cost_est, 0.0)
-            if failed or kind not in ("direct", "sliding", "gear"):
+            if failed or kind not in ("direct", "sliding", "gear",
+                                      "device"):
                 return
             est = max(self.cost.estimate(kind, padded), 1e-9)
             self.cost.observe(kind, padded, wall_s)
             oh, spb = self.cost.params(kind)
             self.policy.observe(padded, actual, n_rows, wall_s, oh, spb)
             dev.launch_hist.record(wall_s)
-            sums = dev.phase_s.setdefault(kind, dict.fromkeys(PHASES, 0.0))
+            sums = dev.phase_s.setdefault(kind, dict.fromkeys(
+                DEVICE_PHASES if kind == "device" else PHASES, 0.0))
             for name, sec in launch.phase_s.items():
                 sums[name] += sec
             dev.queue_s += sum(j.t_exec0 - j.t_submit for j in batch)
@@ -1191,6 +1267,88 @@ class CrystalTPU:
         self._account(dev, len(batch), int(np.sum(lens)),
                       sum(j.lane == "scrub" for j in batch))
 
+    # -- device-resident direct job ------------------------------------
+    def pull_blocks(self, array: jax.Array, idx: List[int],
+                    block_bytes: int) -> Dict[int, np.ndarray]:
+        """Blocks ``idx`` of a device-resident array's fixed blocks,
+        pulled to the host: {block index: uint8 bytes}, a short last
+        block rounded up to a whole word.
+
+        Whole blocks are gathered on the array's device in pieces of
+        power-of-two counts, the binary digits of their number, a short
+        last block on its own; all are pulled together in one
+        ``jax.device_get``.  So a pull moves no block it was not asked
+        for, and the gathers of an array shape compile for at most log2
+        of its block count piece sizes.  The first pull of a shape
+        compiles them all: later pulls of any count compile nothing."""
+        device = next(iter(array.devices()))
+        n_full = int(array.size) * array.dtype.itemsize // block_bytes
+        self._warm_pulls(array, device, block_bytes)
+        full = [i for i in idx if i < n_full]
+        pieces, lo = [], 0
+        for j in reversed(range(len(full).bit_length())):
+            if len(full) >> j & 1:
+                sel = jax.device_put(np.asarray(full[lo:lo + (1 << j)],
+                                                np.int32), device)
+                pieces.append(ops.gather_blocks_device(
+                    array, sel, block_bytes=block_bytes))
+                lo += 1 << j
+        if len(full) < len(idx):
+            pieces.append(ops.tail_block_device(array,
+                                                block_bytes=block_bytes))
+            full.append(n_full)
+        rows = [row for piece in jax.device_get(pieces)
+                for row in np.asarray(piece, "<u4").reshape(
+                    -1, piece.shape[-1])]
+        return {i: row.view(np.uint8) for i, row in zip(full, rows)}
+
+    def _warm_pulls(self, array: jax.Array, device,
+                    block_bytes: int) -> None:
+        """Compile (and run once, pulling nothing) the gather of every
+        power-of-two piece size of ``array``'s whole blocks, and that of
+        its short last block."""
+        key = (array.shape, array.dtype, device, block_bytes)
+        with self._lock:
+            if key in self._pull_shapes:
+                return
+            self._pull_shapes.add(key)
+        n_bytes = int(array.size) * array.dtype.itemsize
+        for j in range((n_bytes // block_bytes).bit_length()):
+            sel = jax.device_put(np.arange(1 << j, dtype=np.int32), device)
+            jax.block_until_ready(ops.gather_blocks_device(
+                array, sel, block_bytes=block_bytes))
+        if n_bytes % block_bytes:
+            jax.block_until_ready(ops.tail_block_device(
+                array, block_bytes=block_bytes))
+
+    def _execute_device(self, dev: _DeviceState, job: Job,
+                        launch: _Launch):
+        """Hash a device-resident job where it lies: rows and lengths
+        are built on its device, the kernel runs on them, and only the
+        digests come back."""
+        n = job.n_rows
+        B = 1 << (n - 1).bit_length()
+        launch.shape(n, B * job.staged_width)
+        with launch.phase("pack"):
+            words, lens_w = ops.pack_blocks_device(
+                job.array, block_bytes=int(job.meta["block_bytes"]),
+                width=job.staged_width, rows=B)
+            self._stage_sync(words)
+        with launch.phase("call"):
+            dig = ops.direct_hash_device(words, lens_w)
+            self._stage_sync(dig)
+        with launch.phase("wait"):          # digests only: 16 B per block
+            host = ops.digest_bytes(dig)
+        with launch.phase("finish"):
+            job.result = host[:n]
+        launch.md5_rows = n
+        launch.md5_lane_rows = ops.md5_lane_rows(B)
+        job.timings = dict(launch.phase_s)
+        self._account(dev, 1, job.n_bytes, int(job.lane == "scrub"))
+        with self._lock:
+            dev.device_jobs += 1
+            dev.device_bytes += job.n_bytes
+
     # -- fused streaming batch (sliding / gear) ------------------------
     def _execute_stream_batch(self, dev: _DeviceState, slot: dict,
                               batch: List[Job], launch: _Launch):
@@ -1252,6 +1410,13 @@ class CrystalTPU:
             j.timings = dict(launch.phase_s)
         self._account(dev, len(batch), int(sum(lens)),
                       sum(j.lane == "scrub" for j in batch))
+
+
+def device_dtype_ok(dtype) -> bool:
+    """Whether a device-resident job may hold an array of ``dtype``: one
+    its device views as the words of its bytes (bool, 1-, 2- and 4-byte
+    types; ``ops.has_device_words``)."""
+    return ops.has_device_words(dtype)
 
 
 # ----------------------------------------------------------------------

@@ -47,6 +47,20 @@ read cache (``SAIConfig.read_cache_bytes``, default off) serves repeat
 reads of hot verified blocks without touching the nodes or the engine
 (hit/miss counters in ``SAI.read_stats``).
 
+Device-resident writes: ``write_async`` / ``write`` of a ``jax.Array``
+on one of the engine's devices (ca='fixed', tpu hasher) never pass its
+bytes through the host on the way to the kernel.  The engine cuts the
+array into fixed blocks and hashes them where they lie; only the
+digests come back.  The store stage claims by digest and then pulls the
+blocks it claimed, and only those: gathered on the device in
+power-of-two pieces and pulled in one ``jax.device_get`` per write
+(``CrystalTPU.pull_blocks``; span ``sai/fetch_new``,
+``WriteStats.stage_s["fetch"]``, ``WriteStats.d2h_bytes``).  Host
+writes run the same store stage over their chunks.  An array whose
+dtype the device cannot view as words (``crystal.device_dtype_ok``:
+8-byte and complex types) is refused: its writer hands over its bytes
+instead.
+
 Configurations mirror the paper's evaluation matrix:
   ca='none'                 -> non-CA (direct write, no hashing)
   ca='fixed'                -> fixed-size blocks + direct hashing
@@ -65,8 +79,9 @@ import threading
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.core import chunking
@@ -113,11 +128,17 @@ class WriteStats:
     ``fingerprint`` (getting the CDC fingerprints: the engine job, or
     the host computation on the cpu hasher) and ``select`` (choosing
     boundaries from them), both inside ``chunk``; ``pack`` (packing
-    chunks into padded rows and submitting them), inside ``hash``."""
+    chunks into padded rows and submitting them), inside ``hash``;
+    ``fetch`` (taking the bytes of the blocks the store claimed: for a
+    device-resident write, their pull from the device), inside
+    ``store``.  ``d2h_bytes`` counts the bytes the write pulled from a
+    device: the engine's digests (16 per block) and a device-resident
+    write's claimed blocks."""
     total_bytes: int = 0
     new_bytes: int = 0
     new_blocks: int = 0
     dup_blocks: int = 0
+    d2h_bytes: int = 0
     stage_s: Dict[str, float] = field(default_factory=dict)
 
     @property
@@ -226,6 +247,49 @@ class _HashHandle:
             self._digests = out
         return self._digests
 
+    @property
+    def d2h_bytes(self) -> int:
+        """Digest bytes pulled from the engine's devices (after wait)."""
+        return 16 * len(self._digests) if self._jobs else 0
+
+
+class _HostBlocks:
+    """A host write's blocks for the store stage: its chunks, as they
+    are."""
+
+    def __init__(self, chunks: List[bytes]):
+        self.chunks = chunks
+        self.lengths = [len(c) for c in chunks]
+        self.pulled = 0
+
+    def fetch(self, idx: List[int]) -> Dict[int, bytes]:
+        return {i: self.chunks[i] for i in idx}
+
+
+class _DeviceBlocks:
+    """A device-resident write's fixed blocks for the store stage, left
+    on the device until the store asks for some: each ``fetch`` gathers
+    the asked blocks there and pulls them in one ``jax.device_get``
+    (``CrystalTPU.pull_blocks``; ``pulled`` counts the bytes moved)."""
+
+    def __init__(self, array: jax.Array, block_size: int,
+                 engine: CrystalTPU):
+        self.engine = engine
+        self.array = array
+        self.block_size = block_size
+        n = _nbytes(array)
+        self.lengths = [min(block_size, n - lo)
+                        for lo in range(0, n, block_size)]
+        self.pulled = 0
+
+    def fetch(self, idx: List[int]) -> Dict[int, bytes]:
+        if not idx:
+            return {}
+        rows = self.engine.pull_blocks(self.array, idx, self.block_size)
+        self.pulled += sum(row.nbytes for row in rows.values())
+        return {i: row[:self.lengths[i]].tobytes()
+                for i, row in rows.items()}
+
 
 def _trace_engine_jobs(trace: "Trace", handle: _HashHandle) -> None:
     """Turn the engine jobs' t_submit/t_exec stamps into
@@ -300,6 +364,44 @@ class SAI:
 
     def _pack_chunks(self, chunks: List[bytes]):
         return pack_blocks(chunks)
+
+    @property
+    def takes_device_data(self) -> bool:
+        """Whether writes may hand this SAI a ``jax.Array`` to hash on
+        its device: fixed blocks hashed by the engine."""
+        return self.cfg.ca == "fixed" and self.cfg.hasher == "tpu"
+
+    def takes_device_array(self, x) -> bool:
+        """Whether ``x`` may be written as it lies on its device: a
+        ``jax.Array`` of a dtype the engine views as words, handed to an
+        SAI that ``takes_device_data``."""
+        return (self.takes_device_data and isinstance(x, jax.Array)
+                and crystal_mod.device_dtype_ok(x.dtype))
+
+    def _blocks(self, data, times: Dict[str, float],
+                trace: Optional[Trace] = None,
+                write: Optional[int] = None):
+        """The chunk stage: the write's blocks, as the store stage takes
+        them (a device-resident write's stay on its device)."""
+        if isinstance(data, jax.Array):
+            times["fingerprint"] = times["select"] = 0.0
+            return _DeviceBlocks(data, self.cfg.block_size, self.engine)
+        return _HostBlocks(self._chunk(data, times, trace, write))
+
+    def _submit_blocks(self, blocks, trace: Optional[Trace] = None,
+                       write: Optional[int] = None) -> _HashHandle:
+        """Start hashing a write's blocks: a device-resident write is
+        one engine job on its own device (its ``sai/pack`` span only
+        submits it: the rows are built on the device)."""
+        if isinstance(blocks, _HostBlocks):
+            return self._submit_hash(blocks.chunks, trace, write)
+        ids = {} if write is None else {"write": write}
+        with span("sai/pack", trace, **ids) as sp:
+            job = self.engine.submit("direct", blocks.array,
+                                     {"block_bytes": blocks.block_size},
+                                     lane=self.cfg.lane)
+            sp.set(seq=job.seq)
+        return _HashHandle(jobs=[job], pack_s=sp.duration_s)
 
     def _submit_hash(self, chunks: List[bytes],
                      trace: Optional[Trace] = None,
@@ -414,12 +516,14 @@ class SAI:
     # ------------------------------------------------------------------
     # store stage (shared by sync write, async pipeline, checkpointer)
     # ------------------------------------------------------------------
-    def _store_chunks(self, path: str, total_len: int,
-                      chunks: List[bytes], digests: List[bytes],
-                      stats: WriteStats,
-                      trace: Optional[Trace] = None) -> WriteStats:
+    def _store_chunks(self, path: str, total_len: int, blocks,
+                      digests: List[bytes], stats: WriteStats,
+                      trace: Optional[Trace] = None,
+                      write: Optional[int] = None) -> WriteStats:
         """Dedup against the indexed digest->locations registry, store
-        novel blocks, commit the block-map.
+        novel blocks, commit the block-map.  ``blocks`` (``_HostBlocks``
+        or ``_DeviceBlocks``) hands over the bytes of the blocks this
+        write claimed, all in one ``fetch`` (span ``sai/fetch_new``).
 
         Dedup is race-free across store lanes and concurrent SAIs: one
         atomic ``claim_blocks`` decides per digest whether it is already
@@ -433,38 +537,49 @@ class SAI:
         freshly stored) block before the block-map referencing it is
         committed."""
         mgr = self.manager
+        lengths = blocks.lengths
         mgr.pin_blocks(digests)
         try:
             locmap, claimed, waits = mgr.claim_blocks(digests)
             new_idx = set()
             try:
-                for i, (chunk, digest) in enumerate(zip(chunks, digests)):
+                first: Dict[bytes, int] = {}
+                for i, digest in enumerate(digests):
                     if digest in claimed:
-                        locs = mgr.place(digest)
-                        self._put_block(path, digest, chunk, locs)
-                        mgr.finish_claim(digest, locs)
-                        claimed.remove(digest)
-                        locmap[digest] = locs
-                        new_idx.add(i)
+                        first.setdefault(digest, i)
+                with span("sai/fetch_new", trace, write=write,
+                          blocks=len(first)) as sp:
+                    got = blocks.fetch(list(first.values()))
+                    sp.set(bytes=sum(len(b) for b in got.values()))
+                stats.stage_s["fetch"] = sp.duration_s
+                for digest, i in first.items():
+                    locs = mgr.place(digest)
+                    self._put_block(path, digest, got.pop(i), locs)
+                    mgr.finish_claim(digest, locs)
+                    claimed.remove(digest)
+                    locmap[digest] = locs
+                    new_idx.add(i)
             finally:
                 for digest in list(claimed):         # error path: release
                     mgr.finish_claim(digest, None)
-            blocks: List[BlockMeta] = []
-            for i, (chunk, digest) in enumerate(zip(chunks, digests)):
+            metas: List[BlockMeta] = []
+            for i, digest in enumerate(digests):
                 locs = locmap.get(digest)
                 if locs is None:
                     waits[digest].wait()
-                    locs, is_new = self._resolve_block(path, digest, chunk)
+                    locs, is_new = self._resolve_block(
+                        path, digest, lambda i=i: blocks.fetch([i])[i])
                     if is_new:
                         new_idx.add(i)
                     locmap[digest] = locs
                 if i in new_idx:
                     stats.new_blocks += 1
-                    stats.new_bytes += len(chunk)
+                    stats.new_bytes += lengths[i]
                 else:
                     stats.dup_blocks += 1
-                blocks.append(BlockMeta(digest, len(chunk), tuple(locs)))
-            seq = mgr.commit_blockmap(path, blocks, total_len)
+                metas.append(BlockMeta(digest, lengths[i], tuple(locs)))
+            stats.d2h_bytes += blocks.pulled
+            seq = mgr.commit_blockmap(path, metas, total_len)
             if self.cfg.durable_sync and seq is not None:
                 t0 = time.perf_counter()
                 mgr.wait_durable(seq)
@@ -485,11 +600,13 @@ class SAI:
             except OSError as e:
                 raise StoreIOError(path, digest, nid, e) from e
 
-    def _resolve_block(self, path: str, digest: bytes, chunk: bytes):
+    def _resolve_block(self, path: str, digest: bytes,
+                       chunk: Callable[[], bytes]):
         """Dup-or-store one block through the claim protocol (used when
         a concurrent writer's claim we waited on aborted): loops until
         the digest is either registered by someone (dup) or claimed and
-        stored by us.  Returns (locations, is_new)."""
+        stored by us, taking its bytes from ``chunk()`` only then.
+        Returns (locations, is_new)."""
         mgr = self.manager
         while True:
             locmap, claimed, waits = mgr.claim_blocks([digest])
@@ -498,7 +615,7 @@ class SAI:
             if claimed:
                 try:
                     locs = mgr.place(digest)
-                    self._put_block(path, digest, chunk, locs)
+                    self._put_block(path, digest, chunk(), locs)
                 except BaseException:
                     mgr.finish_claim(digest, None)
                     raise
@@ -540,25 +657,42 @@ class SAI:
     # ------------------------------------------------------------------
     # write paths
     # ------------------------------------------------------------------
-    def write(self, path: str, data: bytes) -> WriteStats:
+    def write(self, path: str, data) -> WriteStats:
+        """Write ``data``: bytes, or a ``jax.Array`` hashed on its
+        device (``takes_device_data``)."""
         cfg = self.cfg
+        self._check_data(data)
         if cfg.ca == "none":
             return self._write_raw(path, data)
         write = next(self._writes)
-        stats = WriteStats(total_bytes=len(data))
+        stats = WriteStats(total_bytes=_nbytes(data))
         times: Dict[str, float] = {}
         t0 = time.perf_counter()
-        chunks = self._chunk(data, times, write=write)
+        blocks = self._blocks(data, times, write=write)
         t1 = time.perf_counter()
-        handle = self._submit_hash(chunks, write=write)
+        handle = self._submit_blocks(blocks, write=write)
         digests = handle.wait()
         t2 = t1 if cfg.hasher == "infinite" else time.perf_counter()
+        stats.d2h_bytes = handle.d2h_bytes
         with span("sai/store", write=write) as st:
-            self._store_chunks(path, len(data), chunks, digests, stats)
-        stats.stage_s = {"chunk": t1 - t0, "hash": t2 - t1,
-                         "store": st.duration_s, **times,
-                         "pack": handle.pack_s}
+            self._store_chunks(path, stats.total_bytes, blocks, digests,
+                               stats, write=write)
+        stats.stage_s.update({"chunk": t1 - t0, "hash": t2 - t1,
+                              "store": st.duration_s, **times,
+                              "pack": handle.pack_s})
         return stats
+
+    def _check_data(self, data) -> None:
+        if not isinstance(data, jax.Array):
+            return
+        if not self.takes_device_data:
+            raise ValueError(
+                f"a device-resident write needs ca='fixed' and the tpu "
+                f"hasher, not ca={self.cfg.ca!r} hasher="
+                f"{self.cfg.hasher!r}")
+        if not crystal_mod.device_dtype_ok(data.dtype):
+            raise ValueError(f"no device-resident write of {data.dtype}: "
+                             f"write its bytes")
 
     def write_async(self, path: str, data: bytes,
                     trace: Optional[Trace] = None) -> WriteFuture:
@@ -572,12 +706,17 @@ class SAI:
         ``trace`` (an ``obs.Trace``) rides the pipeline queues and
         collects sai/chunk, chunk/select, chunk/split, sai/hash,
         sai/pack, sai/store, engine queue/launch, and wal/commit
-        spans."""
+        spans.  ``data`` may be a ``jax.Array`` on one of the engine's
+        devices (``takes_device_data``): it is hashed there, and only
+        the blocks the store claims leave it."""
+        self._check_data(data)
+        if not isinstance(data, jax.Array):
+            data = bytes(data)
         fut = WriteFuture()
         write = next(self._writes)
         with self._pipe_lock:
             self._ensure_pipeline()
-            self._chunk_q.put((fut, path, bytes(data), trace, write))  # ra: disable=RA04(unbounded queue: put cannot block; hoisting it would race close)
+            self._chunk_q.put((fut, path, data, trace, write))  # ra: disable=RA04(unbounded queue: put cannot block; hoisting it would race close)
         return fut
 
     def flush(self):
@@ -657,15 +796,15 @@ class SAI:
                     continue
                 times: Dict[str, float] = {}
                 t0 = time.perf_counter()
-                chunks = self._chunk(data, times, trace, write)
+                blocks = self._blocks(data, times, trace, write)
                 t1 = time.perf_counter()
                 if trace is not None:
                     trace.add_span("sai/chunk", t0, t1,
-                                   chunks=len(chunks))
+                                   chunks=len(blocks.lengths))
                 # non-blocking (tpu)
-                handle = self._submit_hash(chunks, trace, write)
+                handle = self._submit_blocks(blocks, trace, write)
                 times.update(chunk=t1 - t0, t_hash0=t1, pack=handle.pack_s)
-                store_q.put((fut, path, data, chunks, handle, times, trace,
+                store_q.put((fut, path, data, blocks, handle, times, trace,
                              write))
             except BaseException as e:
                 fut._fail(e)
@@ -681,25 +820,27 @@ class SAI:
                 store_q.task_done()
                 return
             hb.beat()
-            fut, path, data, chunks, handle, times, trace, write = item
+            fut, path, data, blocks, handle, times, trace, write = item
             try:
                 if handle is None:                   # ca='none'
                     fut._resolve(self._write_raw(path, data))
                     continue
-                stats = WriteStats(total_bytes=len(data))
+                stats = WriteStats(total_bytes=_nbytes(data))
                 t_hash0 = times.pop("t_hash0")
                 digests = handle.wait()             # timed, no span
                 t2 = time.perf_counter()
+                stats.d2h_bytes = handle.d2h_bytes
                 if trace is not None:
                     trace.add_span("sai/hash", t_hash0, t2)
                     _trace_engine_jobs(trace, handle)
                 with span("sai/store", trace, write=write) as st:
-                    self._store_chunks(path, len(data), chunks, digests,
-                                       stats, trace=trace)
+                    self._store_chunks(path, stats.total_bytes, blocks,
+                                       digests, stats, trace=trace,
+                                       write=write)
                 hash_s = 0.0 if self.cfg.hasher == "infinite" \
                     else t2 - t_hash0
-                stats.stage_s = {"hash": hash_s, "store": st.duration_s,
-                                 **times}
+                stats.stage_s.update({"hash": hash_s,
+                                      "store": st.duration_s, **times})
                 fut._resolve(stats)
             except BaseException as e:
                 fut._fail(e)
@@ -1052,6 +1193,13 @@ class SAI:
                 fut._fail(e)
             finally:
                 verify_q.task_done()
+
+
+def _nbytes(data) -> int:
+    """Bytes of a write's data (``bytes`` or a ``jax.Array``)."""
+    if isinstance(data, jax.Array):
+        return int(data.size) * data.dtype.itemsize
+    return len(data)
 
 
 def pack_blocks(chunks: List[bytes]):

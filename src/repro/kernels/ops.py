@@ -72,6 +72,109 @@ def direct_hash_device(words: jax.Array, lens_w: jax.Array) -> jax.Array:
     return dig.T[:N]
 
 
+def row_bytes(block_bytes: int) -> int:
+    """Width of a direct-kernel row for a block of ``block_bytes``: the
+    block zero-padded to a word, its 4-byte length trailer, rounded up
+    to a power of two (bounding the shapes a launch compiles for)."""
+    return 1 << ((block_bytes + 3) // 4 * 4 + 4 - 1).bit_length()
+
+
+def has_device_words(dtype) -> bool:
+    """Whether the device can view an array of ``dtype`` as the words of
+    its bytes (``_device_words``): bool, or a real 1-, 2- or 4-byte
+    type whose bits fill its bytes.  Other arrays are hashed from a host
+    copy."""
+    dtype = np.dtype(dtype)
+    if dtype == np.bool_:
+        return True
+    if dtype.itemsize not in (1, 2, 4):
+        return False
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.finfo(dtype).bits == 8 * dtype.itemsize
+    if jnp.issubdtype(dtype, jnp.integer):
+        return jnp.iinfo(dtype).bits == 8 * dtype.itemsize
+    return False
+
+
+def _device_words(x: jax.Array) -> jax.Array:
+    """The bytes of ``x`` (any shape, a dtype ``has_device_words``
+    takes) as little-endian uint32 words, zero-padded to a whole word:
+    what ``x.tobytes()`` viewed as ``<u4`` would give, made on the
+    device."""
+    if not has_device_words(x.dtype):
+        raise ValueError(f"no device bytes for dtype {x.dtype}")
+    if x.dtype == jnp.bool_:                    # one byte, 0 or 1
+        x = x.astype(jnp.uint8)
+    size = x.dtype.itemsize
+    u = jax.lax.bitcast_convert_type(
+        x, {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[size]).reshape(-1)
+    per = 4 // size
+    if per == 1:
+        return u
+    # word j takes elements per*j .. per*j+per-1; strided slices of rows
+    # a lane tile wide keep every array's minor axis at LANES words (a
+    # minor axis of `per` would pad to a whole tile on a TPU)
+    n_words = -(-u.size // per)
+    u = jnp.pad(u, (0, -u.size % (per * LANES))).reshape(-1, per * LANES)
+    words = u[:, 0::per].astype(jnp.uint32)
+    for k in range(1, per):
+        words = words | (u[:, k::per].astype(jnp.uint32)
+                         << jnp.uint32(8 * size * k))
+    return words.reshape(-1)[:n_words]
+
+
+@functools.partial(jax.jit, static_argnames=("block_bytes", "width",
+                                             "rows"))
+def pack_blocks_device(x: jax.Array, block_bytes: int, width: int,
+                       rows: int) -> Tuple[jax.Array, jax.Array]:
+    """The direct kernel's rows for the fixed blocks of ``x``'s bytes,
+    built on the device ``x`` is on: block i (``block_bytes`` bytes, the
+    last one shorter) zero-padded to a word, its u32 byte length after
+    it, in a row of ``width`` bytes; ``rows`` rows (a power of two at
+    least the block count, rows past it empty).  Returns ([rows,
+    width/4] uint32 words, [rows] int32 word lengths), ready for
+    ``direct_hash_device``: the same rows ``core.sai.pack_blocks`` makes
+    on the host from the same bytes."""
+    n_bytes = x.size * x.dtype.itemsize
+    bw, ww = block_bytes // 4, width // 4
+    n = -(-n_bytes // block_bytes)
+    lens = np.full((n,), block_bytes, np.int64)
+    lens[-1] = n_bytes - (n - 1) * block_bytes
+    at = (lens + 3) // 4                       # trailer word of each row
+    cw = int(at.max())                         # words of the widest block
+    if cw >= ww or rows < n:
+        raise ValueError(f"{n} blocks of up to {4 * cw} bytes do not fit "
+                         f"{rows} rows of {width} bytes")
+    words = _device_words(x)
+    words = jnp.pad(words, (0, n * bw - words.size)).reshape(n, bw)
+    out = jnp.pad(words[:, :cw], ((0, rows - n), (0, ww - cw)))
+    out = out.at[np.arange(n), at].set(jnp.asarray(lens, jnp.uint32))
+    lens_w = np.zeros((rows,), np.int32)
+    lens_w[:n] = at + 1
+    return out, jnp.asarray(lens_w)
+
+
+@functools.partial(jax.jit, static_argnames=("block_bytes",))
+def gather_blocks_device(x: jax.Array, idx: jax.Array,
+                         block_bytes: int) -> jax.Array:
+    """Whole blocks ``idx`` of ``x``'s fixed blocks (each below
+    ``n_bytes // block_bytes``), gathered on the device into
+    [len(idx), block_bytes / 4] uint32 rows, so one pull moves only
+    them."""
+    n_full = x.size * x.dtype.itemsize // block_bytes
+    bw = block_bytes // 4
+    words = _device_words(x)[:n_full * bw].reshape(n_full, bw)
+    return jnp.take(words, idx, axis=0)
+
+
+@functools.partial(jax.jit, static_argnames=("block_bytes",))
+def tail_block_device(x: jax.Array, block_bytes: int) -> jax.Array:
+    """The words of ``x``'s last fixed block when it is short (zero-
+    padded to a whole word)."""
+    n_full = x.size * x.dtype.itemsize // block_bytes
+    return _device_words(x)[n_full * (block_bytes // 4):]
+
+
 def digest_bytes(dig) -> np.ndarray:
     """[N, 4] uint32 digests (device or host) -> [N, 16] uint8 host."""
     dig = np.ascontiguousarray(dig, dtype="<u4")
